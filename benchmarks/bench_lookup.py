@@ -190,11 +190,7 @@ def run_engine_tiers(store, batches: List[np.ndarray]) -> Dict:
     entry = probe._entry(store.spec.tasks)
     # One byte under the digits tier's weight requirement: both
     # resident kernel tiers are over budget, head pages still fit.
-    squeeze = (
-        kops.padded_weight_bytes(entry.spec)
-        + kops.activation_bytes(entry.spec, probe.tile_n)
-        - 1
-    )
+    squeeze = kops.resident_bytes(entry.spec, probe.tile_n) - 1
     tiers = (
         ("jit_keys", fresh_engine(False)),
         ("fused", probe),

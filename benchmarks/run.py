@@ -36,6 +36,7 @@ Sections:
 from __future__ import annotations
 
 import argparse
+import os
 import sys
 import traceback
 
@@ -54,6 +55,10 @@ def main() -> None:
              "https://ui.perfetto.dev)",
     )
     args = ap.parse_args()
+
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
     from benchmarks import bench_beyond, bench_breakdown, bench_lookup
     from benchmarks import bench_mhas, bench_modify, bench_query, bench_shards
@@ -121,8 +126,6 @@ def main() -> None:
             print(f"# SECTION {name} FAILED", flush=True)
             traceback.print_exc()
     if args.telemetry_dir:
-        import os
-
         from repro import obs
 
         os.makedirs(args.telemetry_dir, exist_ok=True)

@@ -42,18 +42,12 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import obs
 from repro.core.inference import INT32_MAX, InferTicket
 from repro.kernels import bitvector as bv_kernel
 from repro.launch import mesh as mesh_lib
-
-try:  # jax>=0.4.35 canonical location
-    from jax.experimental.shard_map import shard_map
-    from jax.sharding import PartitionSpec as P
-    _SHARD_MAP = True
-except Exception:  # pragma: no cover - toolchain without shard_map
-    _SHARD_MAP = False
 
 #: Minimum padded batch length — one lane-ish tile, keeps tiny batches
 #: from compiling one program per length.
@@ -149,13 +143,13 @@ def _build_scatter_fn(mesh, layout: _Layout, n_flat: int):
         return codes, exists
 
     in_specs = (P("shard"),) * (7 + n_flat)
-    # check_rep=False: this jax version's replication checker cannot
-    # statically infer that a tiled all_gather output is replicated,
-    # and rejects the (correct) P() out_specs without it.
+    # check_vma=False: a tiled all_gather's output is typed as varying
+    # over "shard", so the checker rejects the (correct) replicated P()
+    # out_specs without it.
     return jax.jit(
-        shard_map(
+        jax.shard_map(
             body, mesh=mesh, in_specs=in_specs, out_specs=(P(), P()),
-            check_rep=False,
+            check_vma=False,
         )
     )
 
@@ -186,16 +180,14 @@ class MeshShardRunner:
         self.k_pad = -(-self.k // self.n_dev) * self.n_dev
         self._stacked = None          # (version, layout, dict of arrays)
         self._fn = None               # jitted scatter fn (per layout)
+        # stacked inputs live split along the shard axis, one block per
+        # device, so a launch moves only the key block
+        self._sharding = NamedSharding(mesh, P("shard"))
 
     # ------------------------------------------------------------ build
     @classmethod
     def maybe_build(cls, shards: Sequence) -> Optional["MeshShardRunner"]:
-        if not _SHARD_MAP:
-            return None
-        try:
-            n_dev = len(jax.devices())
-        except Exception:  # pragma: no cover - backend init failure
-            return None
+        n_dev = len(jax.devices())
         if n_dev < 2 or len(shards) < 2:
             return None
         if not all(cls._shard_eligible(s) for s in shards):
@@ -216,16 +208,31 @@ class MeshShardRunner:
 
     @staticmethod
     def _shard_eligible(s) -> bool:
+        # The scatter runs the jit ladder's XLA forward.  On the TPU the
+        # Pallas tiers round differently, and a shard's T_aux corrects
+        # only the codes of the tiers that built it, so ``use_pallas``
+        # shards stay on their own engines.
         return (
             getattr(s, "vexist", None) is not None
             and getattr(s, "params", None) is not None
             and hasattr(s, "engine")
+            and not s.engine.use_pallas
             and s.encoder.capacity <= INT32_MAX
             and s.vexist.capacity <= INT32_MAX + 1
             and s.spec.dtype == "float32"
         )
 
     # ---------------------------------------------------------- stacking
+    def stacked_devices(self) -> set:
+        """Devices holding the stacked weights and words (empty before
+        the first launch)."""
+        if self._stacked is None:
+            return set()
+        return {
+            d for a in jax.tree.leaves(self._stacked[2])
+            for d in a.sharding.device_set
+        }
+
     def _version(self) -> tuple:
         return tuple((id(s.params), s.vexist.version) for s in self.shards)
 
@@ -354,15 +361,13 @@ class MeshShardRunner:
             base=first.base, n_shared=n_shared,
             hidden=tuple(hidden), n_tasks=len(tasks),
         )
-        arrays = {
-            "mods": jnp.asarray(mods),
-            "divs": jnp.asarray(divs),
-            "cap": jnp.asarray(cap),
-            "vcap": jnp.asarray(vcap),
-            "words": jnp.asarray(words),
-            "cards": jnp.asarray(cards),
-            "flat": tuple(jnp.asarray(a) for a in flat),
-        }
+        arrays = jax.device_put(
+            {
+                "mods": mods, "divs": divs, "cap": cap, "vcap": vcap,
+                "words": words, "cards": cards, "flat": tuple(flat),
+            },
+            self._sharding,
+        )
         if self._stacked is None or self._stacked[1] != layout:
             self._fn = None  # layout changed: rebuild the scatter program
         self._stacked = (version, layout, arrays)
@@ -399,7 +404,8 @@ class MeshShardRunner:
                 valid, k, -1
             ).astype(np.int32)
         codes, exists = self._fn(
-            jnp.asarray(keys_blk), arrays["mods"], arrays["divs"],
+            jax.device_put(keys_blk, self._sharding),
+            arrays["mods"], arrays["divs"],
             arrays["cap"], arrays["vcap"], arrays["words"],
             arrays["cards"], *arrays["flat"],
         )

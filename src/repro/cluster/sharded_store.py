@@ -374,9 +374,10 @@ class ShardedDeepMappingStore(MappingStore):
 
     def _mesh_tickets(self, batches) -> Optional[dict]:
         """Precomputed per-shard inference tickets via the device mesh,
-        or None (thread-pool path).  Any mesh failure degrades to None
-        with a warning + counter — never a failed plan: the per-shard
-        dispatch below answers the same batch."""
+        or None (thread-pool path) when the mesh path declines: one
+        device, < 2 routed batches, or a fleet that is not stackable.
+        A failing mesh program is counted and raised — never hidden
+        behind the thread pool."""
         if len(batches) < 2 or not self._mesh_enabled():
             return None
         runner = self._mesh_runner()
@@ -384,13 +385,12 @@ class ShardedDeepMappingStore(MappingStore):
             return None
         try:
             return runner.tickets(batches)
-        except Exception as exc:
+        except Exception:
             obs.counter(
                 "deepmap_mesh_scatter_failures_total",
-                "Mesh scatter launches degraded to the thread pool.",
+                "Mesh scatter launches that raised.",
             ).inc()
-            warnings.warn(f"mesh scatter failed, using thread pool: {exc}")
-            return None
+            raise
 
     def _mesh_enabled(self) -> bool:
         if not self.cluster.mesh_scatter:

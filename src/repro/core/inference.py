@@ -17,8 +17,8 @@ device side of Algorithm 1 and removes all four:
   compiled (path, spec, bucket) signatures, deduplicated cluster-wide.
 * **Fused key-encode + existence kernel.**  With ``use_pallas`` the
   engine ships RAW int32 keys; digit/residue decomposition happens
-  in-kernel from SMEM ``(modulus, divisor)`` scalars and the packed
-  existence words are tested in the same ``pallas_call`` — codes and
+  in-kernel from compile-time ``(modulus, divisor)`` constants and the packed
+  existence words are tested in the same jitted program — codes and
   exist bits come back in one device round trip
   (``repro.kernels.fused_mlp.fused_lookup_call``).  On the jit path the
   decomposition moves in-graph instead (``_codes_from_keys_jit``).
@@ -243,7 +243,6 @@ class InferenceEngine:
         self._lock = threading.Lock()
         self._entries: Dict[Tuple[str, ...], _TaskEntry] = {}  # guarded-by: _lock
         self._pos_ops = tuple(encoder.position_ops())
-        self._pos_ops_dev = None           # lazy (width, 2) int32 device array
         self._words_cache: Optional[Tuple[int, jnp.ndarray]] = None  # guarded-by: _lock
 
     def bind_vexist(self, vexist) -> None:
@@ -307,11 +306,6 @@ class InferenceEngine:
                 self.stats.bump("word_uploads")
             return self._words_cache[1]
 
-    def _device_pos_ops(self) -> jnp.ndarray:
-        if self._pos_ops_dev is None:
-            self._pos_ops_dev = jnp.asarray(np.asarray(self._pos_ops, dtype=np.int32))
-        return self._pos_ops_dev
-
     def _bucket(self, n: int) -> int:
         b = self.tile_n
         while b < n:
@@ -319,29 +313,23 @@ class InferenceEngine:
         return b
 
     # -------------------------------------------------------- path choice
-    # Eligibility uses shape-derived byte counts (padded_weight_bytes),
+    # Eligibility uses shape-derived byte counts (kops.resident_bytes),
     # NOT entry.flat(): deciding against the Pallas path must not
     # materialize — and permanently cache — a padded device weight copy
     # the jit fallback never touches.
-    def _fused_eligible(self, entry: _TaskEntry) -> bool:
+    def _words_in_domain(self) -> bool:
         v = self.vexist
-        if v is None or self.encoder.capacity > INT32_MAX:
-            return False
-        if v.capacity > INT32_MAX + 1:
-            return False
-        vmem = (
-            kops.padded_weight_bytes(entry.spec)
-            + kops.activation_bytes(entry.spec, self.tile_n)
-            + int(v.words.nbytes)
+        return v is not None and v.capacity <= INT32_MAX + 1
+
+    def _fused_eligible(self, entry: _TaskEntry) -> bool:
+        return (
+            self._words_in_domain()
+            and self.encoder.capacity <= INT32_MAX
+            and self._pallas_eligible(entry)
         )
-        return vmem <= self.vmem_budget
 
     def _pallas_eligible(self, entry: _TaskEntry) -> bool:
-        return (
-            kops.padded_weight_bytes(entry.spec)
-            + kops.activation_bytes(entry.spec, self.tile_n)
-            <= self.vmem_budget
-        )
+        return kops.resident_bytes(entry.spec, self.tile_n) <= self.vmem_budget
 
     def kernel_filter_capable(
         self, tasks: Optional[Tuple[str, ...]] = None
@@ -366,31 +354,18 @@ class InferenceEngine:
     ) -> Optional[Tuple[Tuple[Tuple[str, ...], ...], bool]]:
         """Page plan for the ``fused_streamed`` tier, or None when it
         cannot apply.  Returns ``(pages, with_exists)`` — existence
-        rides with page 0 only when the bitvector fits alongside that
-        page's heads; without ``want_exists`` (or without a bitvector)
-        every page is codes-only and the caller tests existence on the
-        host like the other non-fused tiers."""
+        rides with page 0; without ``want_exists`` (or without a
+        bitvector in the int32 domain) every page is codes-only and the
+        caller tests existence on the host like the other non-fused
+        tiers."""
         if self.encoder.capacity > INT32_MAX:
             return None
-        v = self.vexist
-        with_exists = (
-            want_exists and v is not None and v.capacity <= INT32_MAX + 1
-        )
-        words_bytes = int(v.words.nbytes) if with_exists else 0
         pages = kops.plan_head_pages(
-            entry.spec, self.tile_n, words_bytes=words_bytes,
-            budget=self.vmem_budget,
+            entry.spec, self.tile_n, budget=self.vmem_budget
         )
-        if pages is None and with_exists:
-            # Words + any head over budget: stream codes-only pages and
-            # leave existence to the host fallback.
-            with_exists = False
-            pages = kops.plan_head_pages(
-                entry.spec, self.tile_n, budget=self.vmem_budget
-            )
         if pages is None:
             return None
-        return pages, with_exists
+        return pages, want_exists and self._words_in_domain()
 
     # ---------------------------------------------------- dispatch/collect
     def dispatch(
@@ -491,12 +466,12 @@ class InferenceEngine:
         ptasks, ptabs = self._kernel_pred_tables(entry, pred_tables)
         self.stats.bump("fused_calls")
         self.stats.note_compile(
-            ("fused", entry.spec, self.encoder.capacity, bucket,
+            ("fused", entry.spec, self._pos_ops, bucket,
              words.shape[0], ptasks, tuple(t.shape[0] for t in ptabs))
         )
         codes, exists, match = kops.fused_lookup(
             flat, entry.spec, jnp.asarray(self._keys_i32(keys, bucket)),
-            self._device_pos_ops(), words, self.encoder.capacity,
+            self._pos_ops, words, self.encoder.capacity,
             tile_n=self.tile_n, interpret=self.interpret,
             pred_tables=ptabs, pred_tasks=ptasks,
         )
@@ -519,7 +494,6 @@ class InferenceEngine:
         conformance the resident tier already guarantees.  Existence
         rides with page 0 when ``with_exists``."""
         keys_dev = jnp.asarray(self._keys_i32(keys, bucket))
-        pos_ops = self._device_pos_ops()
         words = self._device_words() if with_exists else None
         self.stats.bump("fused_streamed_calls")
         codes_pages = []
@@ -529,14 +503,13 @@ class InferenceEngine:
             flat, _ = page_entry.flat()
             page_exists = with_exists and i == 0
             self.stats.note_compile(
-                ("fused_streamed", page_entry.spec, self.encoder.capacity,
+                ("fused_streamed", page_entry.spec, self._pos_ops,
                  bucket, words.shape[0] if page_exists else 0, page_exists)
             )
             codes, ex, _ = kops.fused_lookup(
-                flat, page_entry.spec, keys_dev, pos_ops,
+                flat, page_entry.spec, keys_dev, self._pos_ops,
                 words if page_exists else None, self.encoder.capacity,
                 tile_n=self.tile_n, interpret=self.interpret,
-                with_exists=page_exists,
             )
             codes_pages.append(codes)
             if page_exists:
@@ -550,7 +523,7 @@ class InferenceEngine:
     def _dispatch_jit_keys(self, keys, tasks, entry, bucket, want_exists):
         self.stats.bump("jit_calls")
         self.stats.note_compile(
-            ("jit_keys", entry.spec, self.encoder.capacity, bucket)
+            ("jit_keys", entry.spec, self._pos_ops, bucket)
         )
         codes = _codes_from_keys_jit(
             entry.params, jnp.asarray(self._keys_i32(keys, bucket)),
@@ -579,6 +552,7 @@ class InferenceEngine:
             jnp.asarray(dp), flat, entry.spec, self.tile_n,
             kops._round_up(entry.spec.base, kops.LANE), entry.card_pads(),
             emit_codes=True, interpret=self.interpret,
+            vmem_limit_bytes=kops.kernel_vmem_limit(self.interpret),
         )
         return InferTicket(n=keys.shape[0], tasks=tasks, path="pallas_digits",
                            keys=keys, want_exists=want_exists,

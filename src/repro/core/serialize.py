@@ -248,6 +248,9 @@ def save_store(store: DeepMappingStore, path: str) -> None:
             "codec": store.config.codec,
             "partition_bytes": store.config.partition_bytes,
             "base": store.config.base,
+            # T_aux corrects the inference path it was evaluated with,
+            # so the reopened store must serve through the same one.
+            "use_pallas": store.config.use_pallas,
         },
         "raw_bytes": store.raw_bytes,
         "num_rows": store.num_rows,
@@ -331,6 +334,7 @@ def load_store(path: str, pool: MemoryPool | None = None) -> DeepMappingStore:
         base=meta["config"]["base"],
         codec=meta["config"]["codec"],
         partition_bytes=meta["config"]["partition_bytes"],
+        use_pallas=bool(meta["config"].get("use_pallas", False)),
     )
     store = DeepMappingStore(
         encoder=enc,

@@ -8,9 +8,9 @@ memorization MLP — dominates device time.  Kernels:
   kernel; optionally emits argmax codes instead of logits so HBM writes
   are O(tasks) int32 per row instead of O(Σ card) floats.  Its
   ``fused_lookup_call`` variant takes RAW int32 keys — digit/residue
-  decomposition happens in-kernel from SMEM scalars — and fuses the
-  existence-bitvector test into the same ``pallas_call``, so Algorithm
-  1 lines 3+5 are one device round trip (driven by
+  decomposition happens in-kernel by compile-time constants — and runs
+  the existence-bitvector test in the same jitted program, so Algorithm
+  1 lines 3+5 are one dispatch (driven by
   ``repro.core.inference.InferenceEngine``).
 * ``bitvector``   — standalone packed-word existence test (line 5).
 * ``ref``         — pure-jnp oracles for all of the above.

@@ -14,10 +14,10 @@ Two entrypoints share the forward body:
   kernel; still the reference-shaped staged path).
 * ``fused_lookup_call`` — RAW int32 keys in, per-task argmax codes AND
   existence bits out.  Digit/residue decomposition happens in-kernel
-  from per-position ``(modulus, divisor)`` scalars held in SMEM, so the
-  HBM input shrinks from ``(N, width)`` int32 to ``(N,)`` keys, and the
-  packed existence-bitvector word array rides in the same
-  ``pallas_call`` (Algorithm 1 lines 3+5 in one device round trip).
+  from per-position ``(modulus, divisor)`` compile-time constants, so the
+  HBM input shrinks from ``(N, width)`` int32 to ``(N, 1)`` keys; the
+  existence-word and predicate-table gathers run beside the kernel in
+  the same jitted program (Algorithm 1 lines 3+5 in one dispatch).
 
 Layout contract (enforced by ops.py):
 * every dense dimension padded to multiples of 128 (MXU lane width);
@@ -25,26 +25,30 @@ Layout contract (enforced by ops.py):
 * rank-3 first-layer weights are (width, base_pad, h_pad);
 * with ``emit_codes=True`` each head reduces to int32 argmax codes
   in-kernel (padded logit columns masked to -inf), shrinking the HBM
-  write from O(Σ cards) floats to one int32 per task per row.
+  write from O(Σ cards) floats to one int32 per task per row;
+* ``vmem_limit_bytes`` (from ``ops.kernel_vmem_limit``) raises the
+  compiler's scoped VMEM limit to what the residency budget admits.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Dict, List, Tuple
+from typing import Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # TPU memory spaces; interpret mode accepts them on any backend
-    from jax.experimental.pallas import tpu as pltpu
-
-    _SMEM = pltpu.SMEM
-except Exception:  # pragma: no cover — pallas build without TPU support
-    _SMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.model import MLPSpec
+
+
+def _compiler_params(vmem_limit_bytes: Optional[int]):
+    """Mosaic params raising the scoped VMEM limit (None: compiler
+    default; interpret mode ignores them)."""
+    if vmem_limit_bytes is None:
+        return None
+    return pltpu.CompilerParams(vmem_limit_bytes=int(vmem_limit_bytes))
 
 
 def _plan(spec: MLPSpec) -> Tuple[List[str], Dict[str, List[str]]]:
@@ -157,7 +161,10 @@ def make_fused_kernel(
 
 @functools.partial(
     jax.jit,
-    static_argnames=("spec", "tile_n", "base_pad", "card_pads", "emit_codes", "interpret"),
+    static_argnames=(
+        "spec", "tile_n", "base_pad", "card_pads", "emit_codes", "interpret",
+        "vmem_limit_bytes",
+    ),
 )
 def fused_mlp_call(
     digits: jnp.ndarray,
@@ -168,6 +175,7 @@ def fused_mlp_call(
     card_pads: Tuple[Tuple[str, int], ...],
     emit_codes: bool,
     interpret: bool,
+    vmem_limit_bytes: Optional[int] = None,
 ):
     """digits (N_pad, width) int32; flat_weights in plan order (padded).
 
@@ -202,193 +210,141 @@ def fused_mlp_call(
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shapes,
+        compiler_params=_compiler_params(vmem_limit_bytes),
         interpret=interpret,
     )(digits, *flat_weights)
 
 
 # --------------------------------------------------------------------------
-# Fused key-encode + inference + existence kernel (one round trip lookup)
+# Fused key-encode + inference + existence (one dispatch lookup)
 # --------------------------------------------------------------------------
 def make_fused_lookup_kernel(
     spec: MLPSpec,
     base_pad: int,
+    pos_ops: Tuple[Tuple[int, int], ...],
     capacity: int,
-    n_words32: int,
-    pred_tasks: Tuple[int, ...] = (),
-    with_exists: bool = True,
 ):
-    """Kernel body answering Algorithm 1 lines 3+5 from raw int32 keys.
+    """Kernel body answering Algorithm 1 line 3 from raw int32 keys.
 
-    Per tile: decompose keys into digit/residue positions from the SMEM
-    ``(modulus, divisor)`` table, run the whole multi-task model, argmax
-    to codes, and test the VMEM-resident packed existence words — codes
-    and exist bits leave in the same HBM write set.  Keys outside
-    ``[0, capacity)`` get code 0 (the host zero-fill contract of
-    ``_infer_codes``) and keys outside the word domain exist=0, exactly
-    matching ``BitVector.test``.
-
-    With ``with_exists=False`` (streamed pages past the first) the
-    words input and existence output are absent.  ``pred_tasks`` adds
-    one boolean code-table input per pushdown predicate plus a single
-    match-bit output: ``match = exists AND table_j[code(pred_tasks[j])]
-    for all j`` — the same conjunction the host filter evaluates, so
-    pushdown plans leave the kernel with their filtering already done.
+    Per tile: decompose keys into digit/residue positions by the static
+    ``(modulus, divisor)`` pairs, run the whole multi-task model, and
+    argmax to codes.  Keys outside ``[0, capacity)`` are clamped to 0
+    for the decomposition; :func:`fused_lookup_call` masks their codes.
     """
     trunk_kinds, head_kinds = _plan(spec)
-    width = spec.width
     base = spec.base
     n_heads = len(spec.tasks)
-    n_preds = len(pred_tasks)
-    if n_preds and not with_exists:
-        raise ValueError("in-kernel predicate filtering requires with_exists")
-    n_outs = n_heads + (1 if with_exists else 0) + (1 if n_preds else 0)
 
-    def kernel(keys_ref, ops_ref, *refs):
-        # refs = [words]?, pred tables..., weights..., then outputs:
-        # codes (one per task), [exists]?, [match]?
-        idx = 1 if with_exists else 0
-        words_ref = refs[0] if with_exists else None
-        table_refs = refs[idx : idx + n_preds]
-        w_refs = list(refs[idx + n_preds : len(refs) - n_outs])
-        out_refs = refs[len(refs) - n_outs :]
-
-        keys = keys_ref[...]
-        in_cap = (keys >= 0) & (keys < capacity)
-        safe = jnp.where(in_cap, keys, 0)
+    def kernel(keys_ref, *refs):
+        w_refs = list(refs[: len(refs) - n_heads])
+        out_refs = refs[len(refs) - n_heads :]
+        keys = keys_ref[...]                       # (tile_n, 1)
+        safe = jnp.where((keys >= 0) & (keys < capacity), keys, 0)
 
         # In-kernel digit/residue decomposition.  Every position is the
-        # same three integer ops on scalars prefetched to SMEM; main
-        # digit positions carry modulus == capacity (a no-op for keys
-        # already clamped into [0, capacity)).
-        cols = []
-        for p in range(width):
-            mod = ops_ref[p, 0]
-            div = ops_ref[p, 1]
-            cols.append((((safe % mod) // div) % base).astype(jnp.int32)[:, None])
+        # same three integer ops by compile-time constants (Mosaic
+        # emulates division by a runtime scalar at several times the
+        # code size); main digit positions carry modulus == capacity (a
+        # no-op for keys already clamped into [0, capacity)).
+        cols = [((safe % mod) // div) % base for mod, div in pos_ops]
         digits = jnp.concatenate(cols, axis=1)
 
         outs = _forward_tile(
             digits, w_refs, spec, trunk_kinds, head_kinds, base_pad,
             emit_codes=True,
         )
-        codes = []
         for ti in range(n_heads):
-            c = jnp.where(in_cap[:, None], outs[ti], 0)
-            codes.append(c)
-            out_refs[ti][...] = c
-
-        if with_exists:
-            # Existence test against the packed words (Algorithm 1 line
-            # 5).  Bits past BitVector.capacity are never set, so the
-            # word-domain mask alone reproduces BitVector.test
-            # byte-for-byte.
-            in_dom = (keys >= 0) & (
-                jax.lax.shift_right_logical(keys, 5) < n_words32
-            )
-            sk = jnp.where(in_dom, keys, 0)
-            w = jnp.take(
-                words_ref[...], jax.lax.shift_right_logical(sk, 5), axis=0
-            )
-            bits = jnp.bitwise_and(
-                jax.lax.shift_right_logical(
-                    w, jnp.bitwise_and(sk, 31).astype(jnp.uint32)
-                ),
-                jnp.uint32(1),
-            )
-            exists = bits.astype(jnp.int32) * in_dom.astype(jnp.int32)
-            out_refs[n_heads][...] = exists
-
-            if n_preds:
-                # The host contract (hybrid._collect_lookup): match
-                # starts as the existence bit and ANDs each predicate's
-                # table at the (in_cap-masked) model code — rows the aux
-                # table later overrides are re-patched host-side.
-                m = exists
-                for j in range(n_preds):
-                    code = codes[pred_tasks[j]][:, 0]
-                    m = m * jnp.take(table_refs[j][...], code, axis=0)
-                out_refs[n_heads + 1][...] = m
+            out_refs[ti][...] = outs[ti]
 
     return kernel
+
+
+def _test_words(words32: jnp.ndarray, keys: jnp.ndarray) -> jnp.ndarray:
+    """(n,) int32 existence bits of int32 keys against packed uint32
+    words.  Bits past ``BitVector.capacity`` are never set, so the
+    word-domain mask alone reproduces ``BitVector.test`` byte-for-byte."""
+    in_dom = (keys >= 0) & (
+        jax.lax.shift_right_logical(keys, 5) < words32.shape[0]
+    )
+    sk = jnp.where(in_dom, keys, 0)
+    w = jnp.take(words32, jax.lax.shift_right_logical(sk, 5), axis=0)
+    bits = jnp.bitwise_and(
+        jax.lax.shift_right_logical(w, jnp.bitwise_and(sk, 31).astype(jnp.uint32)),
+        jnp.uint32(1),
+    )
+    return bits.astype(jnp.int32) * in_dom.astype(jnp.int32)
 
 
 @functools.partial(
     jax.jit,
     static_argnames=(
-        "spec", "tile_n", "base_pad", "capacity", "interpret",
-        "pred_tasks", "with_exists",
+        "spec", "tile_n", "base_pad", "pos_ops", "capacity", "interpret",
+        "pred_tasks", "vmem_limit_bytes",
     ),
 )
 def fused_lookup_call(
     keys: jnp.ndarray,
-    pos_ops: jnp.ndarray,
     words32,
     flat_weights: Tuple[jnp.ndarray, ...],
     spec: MLPSpec,
     tile_n: int,
     base_pad: int,
+    pos_ops: Tuple[Tuple[int, int], ...],
     capacity: int,
     interpret: bool,
     pred_tables: Tuple[jnp.ndarray, ...] = (),
     pred_tasks: Tuple[int, ...] = (),
-    with_exists: bool = True,
+    vmem_limit_bytes: Optional[int] = None,
 ):
-    """keys (N_pad,) int32; pos_ops (width, 2) int32 [(mod, div)…];
-    words32 (n_words32,) uint32 (None when ``with_exists=False``);
+    """keys (N_pad,) int32; pos_ops ``((mod, div), …)`` per digit
+    position (``KeyEncoder.position_ops``); words32 (n_words32,) uint32, or None to skip the existence test;
     flat_weights in plan order (padded); pred_tables one padded int32
     0/1 vector per pushdown predicate, indexed by the code of head
     ``pred_tasks[j]``.
 
     Returns ``(codes, exists, match)``: codes (N_pad, num_tasks) int32;
-    exists (N_pad,) int32 0/1 or None without ``with_exists``; match
-    (N_pad,) int32 0/1 or None without ``pred_tables`` — one device
-    round trip for the whole batch.
+    exists (N_pad,) int32 0/1 or None without ``words32``; match
+    (N_pad,) int32 0/1 or None without ``pred_tables``.
+
+    The model runs in the ``pallas_call``; the existence-word and
+    predicate-table gathers run in the same jitted program around it
+    (Mosaic lowers no 1-D vector gather), so a lookup is still one
+    dispatch and one device round trip.  ``match = exists AND
+    table_j[code(pred_tasks[j])]`` for all j — the conjunction the host
+    filter evaluates — so pushdown plans leave the device with their
+    filtering already done.
     """
     n = keys.shape[0]
     if n % tile_n != 0:
         raise ValueError(f"batch size {n} must be a multiple of tile_n={tile_n}")
-    grid = (n // tile_n,)
-    n_heads = len(spec.tasks)
-    kernel = make_fused_lookup_kernel(
-        spec, base_pad, capacity,
-        words32.shape[0] if with_exists else 0,
-        pred_tasks=pred_tasks, with_exists=with_exists,
-    )
-
-    smem_kwargs = {"memory_space": _SMEM} if _SMEM is not None else {}
-    inputs = [keys, pos_ops]
+    if pred_tables and words32 is None:
+        raise ValueError("in-kernel predicate filtering requires existence words")
+    kernel = make_fused_lookup_kernel(spec, base_pad, pos_ops, capacity)
+    # Keys enter as an (N, 1) column: a 1-D block's tiling must match
+    # XLA's layout of the whole array, which a (tile_n,) block does not.
     in_specs = [
-        pl.BlockSpec((tile_n,), lambda i: (i,)),
-        pl.BlockSpec(pos_ops.shape, lambda i: (0, 0), **smem_kwargs),
+        pl.BlockSpec((tile_n, 1), lambda i: (i, 0)),
     ]
-    if with_exists:
-        inputs.append(words32)
-        in_specs.append(pl.BlockSpec(words32.shape, lambda i: (0,)))
-    for tb in pred_tables:
-        inputs.append(tb)
-        in_specs.append(pl.BlockSpec(tb.shape, lambda i: (0,)))
     for w in flat_weights:
-        inputs.append(w)
         in_specs.append(pl.BlockSpec(w.shape, lambda i, nd=w.ndim: (0,) * nd))
-
-    out_shapes = [jax.ShapeDtypeStruct((n, 1), jnp.int32) for _ in spec.tasks]
-    out_specs = [pl.BlockSpec((tile_n, 1), lambda i: (i, 0)) for _ in spec.tasks]
-    if with_exists:
-        out_shapes.append(jax.ShapeDtypeStruct((n,), jnp.int32))
-        out_specs.append(pl.BlockSpec((tile_n,), lambda i: (i,)))
-    if pred_tables:
-        out_shapes.append(jax.ShapeDtypeStruct((n,), jnp.int32))
-        out_specs.append(pl.BlockSpec((tile_n,), lambda i: (i,)))
-
     outs = pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(n // tile_n,),
         in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shapes,
+        out_specs=[pl.BlockSpec((tile_n, 1), lambda i: (i, 0)) for _ in spec.tasks],
+        out_shape=[jax.ShapeDtypeStruct((n, 1), jnp.int32) for _ in spec.tasks],
+        compiler_params=_compiler_params(vmem_limit_bytes),
         interpret=interpret,
-    )(*inputs)
-    codes = jnp.concatenate(outs[:n_heads], axis=1)
-    exists = outs[n_heads] if with_exists else None
-    match = outs[-1] if pred_tables else None
+    )(keys[:, None], *flat_weights)
+    # Out-of-capacity keys answer code 0 (the ``_infer_codes`` contract).
+    in_cap = (keys >= 0) & (keys < capacity)
+    codes = jnp.where(in_cap[:, None], jnp.concatenate(outs, axis=1), 0)
+    if words32 is None:
+        return codes, None, None
+    exists = _test_words(words32, keys)
+    match = None
+    if pred_tables:
+        match = exists
+        for table, ti in zip(pred_tables, pred_tasks):
+            match = match * jnp.take(table, codes[:, ti], axis=0)
     return codes, exists, match

@@ -15,13 +15,9 @@ import jax
 
 
 def _make_mesh(shape, axes):
-    """Auto-typed mesh on any jax version: older releases predate
-    ``jax.sharding.AxisType`` and treat every axis as Auto already."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
-        )
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):

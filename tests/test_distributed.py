@@ -21,17 +21,6 @@ def run_in_subprocess(body: str, devices: int = 8, timeout: int = 600):
         f"""
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count={devices}"
-        import jax
-        if not hasattr(jax.sharding, "AxisType"):
-            # Older jax: meshes are Auto-typed by default; accept and
-            # drop the axis_types kwarg so the snippets below run as-is.
-            class _AxisType:
-                Auto = None
-            jax.sharding.AxisType = _AxisType
-            _orig_make_mesh = jax.make_mesh
-            jax.make_mesh = (
-                lambda shape, names, axis_types=None: _orig_make_mesh(shape, names)
-            )
         """
     ) + textwrap.dedent(body)
     env = dict(os.environ)
@@ -150,7 +139,7 @@ class TestHierarchicalCollectives:
             """
             import jax, jax.numpy as jnp, numpy as np
             from jax.sharding import PartitionSpec as P
-            from jax.experimental.shard_map import shard_map
+            from jax import shard_map
             from repro.train.compression import hierarchical_psum
 
             mesh = jax.make_mesh((2, 4), ("pod", "data"),
@@ -173,7 +162,7 @@ class TestHierarchicalCollectives:
             """
             import jax, jax.numpy as jnp, numpy as np
             from jax.sharding import PartitionSpec as P
-            from jax.experimental.shard_map import shard_map
+            from jax import shard_map
             from repro.train.compression import compressed_cross_pod_mean, ef_init
 
             mesh = jax.make_mesh((4, 2), ("pod", "data"),

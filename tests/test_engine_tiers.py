@@ -37,12 +37,7 @@ def _engine(enc, spec, params, bv, monkeypatch, budget=None):
 def _fused_vmem(eng) -> int:
     """Bytes the resident fused tier needs for the full task set —
     the exact quantity ``_fused_eligible`` compares to the budget."""
-    entry = eng._entry(eng.spec.tasks)
-    return (
-        kops.padded_weight_bytes(entry.spec)
-        + kops.activation_bytes(entry.spec, eng.tile_n)
-        + int(eng.vexist.words.nbytes)
-    )
+    return kops.resident_bytes(eng._entry(eng.spec.tasks).spec, eng.tile_n)
 
 
 def _assert_ref_identical(eng, enc, spec, params, bv, keys):
@@ -103,11 +98,8 @@ class TestVmemBoundaryTiers:
         enc, spec, params, bv = self.setup
         probe = _engine(enc, spec, params, bv, monkeypatch)
         entry = probe._entry(spec.tasks)
-        pallas_vmem = kops.padded_weight_bytes(
-            entry.spec
-        ) + kops.activation_bytes(entry.spec, TILE)
         eng = _engine(
-            enc, spec, params, bv, monkeypatch, budget=pallas_vmem - 1
+            enc, spec, params, bv, monkeypatch, budget=_fused_vmem(probe) - 1
         )
         # the squeezed budget must still admit a single-head page
         assert eng._streamed_plan(entry, True) is not None
@@ -122,12 +114,8 @@ class TestVmemBoundaryTiers:
     def test_streamed_handles_out_of_domain_keys(self, monkeypatch):
         enc, spec, params, bv = self.setup
         probe = _engine(enc, spec, params, bv, monkeypatch)
-        entry = probe._entry(spec.tasks)
-        pallas_vmem = kops.padded_weight_bytes(
-            entry.spec
-        ) + kops.activation_bytes(entry.spec, TILE)
         eng = _engine(
-            enc, spec, params, bv, monkeypatch, budget=pallas_vmem - 1
+            enc, spec, params, bv, monkeypatch, budget=_fused_vmem(probe) - 1
         )
         keys = np.array(
             [0, 1, 9999, 10000, 10001, 2**31 - 1, 2**31, 2**40, -1, -7],
@@ -145,6 +133,31 @@ class TestVmemBoundaryTiers:
         assert not _engine(
             enc, spec, params, bv, monkeypatch, budget=full - 1
         ).kernel_filter_capable()
+
+
+class TestVmemBudgetTable:
+    """The budget comes from the TPU's ``device_kind``; an unknown TPU
+    kind is an error, and off the TPU the CPU default holds."""
+
+    def _on(self, monkeypatch, platform, kind):
+        dev = type("Dev", (), {"platform": platform, "device_kind": kind})()
+        monkeypatch.delenv("REPRO_VMEM_BUDGET", raising=False)
+        monkeypatch.setattr(kops.jax, "devices", lambda *a: [dev])
+
+    def test_cpu_keeps_default(self, monkeypatch):
+        self._on(monkeypatch, "cpu", "cpu")
+        assert kops.vmem_budget_bytes() == kops.VMEM_BUDGET_BYTES
+
+    def test_tpu_kind_from_table(self, monkeypatch):
+        self._on(monkeypatch, "tpu", "TPU v5 lite")
+        want = kops._TPU_VMEM_BUDGETS["TPU v5 lite"]
+        assert kops.vmem_budget_bytes() == want
+        assert kops.vmem_limit_bytes() == want
+
+    def test_unknown_tpu_kind_is_an_error(self, monkeypatch):
+        self._on(monkeypatch, "tpu", "TPU v0 imaginary")
+        with pytest.raises(ValueError, match="no VMEM budget"):
+            kops.vmem_budget_bytes()
 
 
 PRED_CASES = [

@@ -95,9 +95,9 @@ class TestFusedMLP:
         assert got.shape == (77, 1)
 
     def test_vmem_budget_rejects_oversized(self):
-        spec, params = make_model((2048, 2048), (2048,), (1000,))
+        spec, _ = make_model((2048, 2048), (2048,), (1000,))
         with pytest.raises(ValueError, match="VMEM"):
-            check_vmem_budget(params, spec, tile_n=256)
+            check_vmem_budget(spec, tile_n=256)
 
     def test_store_with_pallas_path_lossless(self):
         """End-to-end: hybrid store built AND queried via the kernel."""

@@ -6,6 +6,7 @@ The multi-device cases need ≥ 2 devices — CI provides them with
 ``XLA_FLAGS=--xla_force_host_platform_device_count=8``; under a plain
 single-device run they skip and the fallback tests still execute."""
 
+import dataclasses
 import os
 
 import numpy as np
@@ -78,6 +79,39 @@ class TestUnits:
     def test_maybe_build_rejects_small_fleets(self, cluster):
         _, c = cluster
         assert MeshShardRunner.maybe_build(c.shards[:1]) is None
+
+    def test_pallas_shards_decline(self):
+        """``use_pallas`` shards keep their own Pallas engines: their
+        T_aux corrects Pallas codes, not the scatter's XLA forward."""
+        table = make_periodic_table(n=1200, period=16, cards=(5, 3))
+        c = ShardedDeepMappingStore.build(
+            table, dataclasses.replace(FAST, use_pallas=True),
+            ClusterConfig(num_shards=2, policy="range"),
+        )
+        assert not any(MeshShardRunner._shard_eligible(s) for s in c.shards)
+        assert MeshShardRunner.maybe_build(c.shards) is None
+        keys = probe_keys(table)
+        values, exists, _, stats = direct_lookup(c, keys)
+        assert not any("mesh" in p for p in stats.plan), stats.plan
+        np.testing.assert_array_equal(exists, np.isin(keys, table.keys))
+
+    def test_failing_mesh_program_raises(self, cluster, monkeypatch):
+        """A mesh program that raises fails the plan and is counted —
+        the thread pool does not answer in its place."""
+        from repro import obs
+
+        table, c = cluster
+
+        class BrokenRunner:
+            def tickets(self, batches):
+                raise RuntimeError("mesh program failed")
+
+        monkeypatch.setattr(c, "_mesh_runner", lambda: BrokenRunner())
+        counter = obs.counter("deepmap_mesh_scatter_failures_total")
+        before = counter.value()
+        with pytest.raises(RuntimeError, match="mesh program failed"):
+            c.lookup(table.keys)
+        assert counter.value() == before + 1
 
     @pytest.mark.skipif(N_DEV != 1, reason="single-device fallback case")
     def test_single_device_fallback_is_noop(self, cluster):
