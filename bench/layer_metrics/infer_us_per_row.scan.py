@@ -1,0 +1,12 @@
+"""Engine time per row of a group-by scan: the queries'
+``ExplainStats.infer_s`` (dispatch plus the host's wait on the device)
+over the rows aggregated in the window.
+
+Returns None where the run has nothing to read."""
+
+
+def read(ctx):
+    seconds = ctx["spans"].get("scan.infer_s")
+    if seconds is None or not ctx["work"]:
+        return None
+    return 1e6 * seconds / ctx["work"]
