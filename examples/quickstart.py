@@ -198,15 +198,22 @@ def main() -> None:
     plan_lat = obs.registry().get("deepmap_executor_plan_seconds")
     print(f"  morsels executed: {int(sum(v for _, v in morsels.items()))}; "
           f"scan plan p50 {plan_lat.quantile(0.5, kind='scan')*1e3:.1f} ms")
-    dispatch = obs.tracer().spans("infer_dispatch", track="device")
-    collect = obs.tracer().spans("collect", track="host")
-    overlaps = sum(
-        1 for d in dispatch for c in collect
-        if d.start < c.start and c.end < d.end
-    )
-    print(f"  trace: {len(dispatch)} device dispatch spans, "
-          f"{len(collect)} host collect spans, "
-          f"{overlaps} pipelined overlaps (dispatch i+1 covers collect i)")
+    # Within the scan's plan span, morsel i+1 is dispatched (an
+    # engine.dispatch span outside any collect) before the collect span
+    # of morsel i ends.
+    plan = obs.tracer().spans("plan")[-1]
+
+    def in_plan(name):
+        return sorted((s for s in obs.tracer().spans(name)
+                       if plan.start <= s.start and s.end <= plan.end),
+                      key=lambda s: s.start)
+
+    dispatch = [s for s in in_plan("engine.dispatch") if not s.parent]
+    collect = in_plan("collect")
+    overlaps = sum(1 for d, c in zip(dispatch[1:], collect) if d.start < c.end)
+    print(f"  trace: {len(dispatch)} engine.dispatch spans, "
+          f"{len(collect)} collect spans, "
+          f"{overlaps} pipelined overlaps (dispatch i+1 before collect i ends)")
     print(f"  Prometheus text:   {prom}")
     print(f"  JSON snapshot:     {snap}")
     print(f"  Chrome trace:      {trace}  (open at https://ui.perfetto.dev)")

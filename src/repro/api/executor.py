@@ -96,8 +96,7 @@ MORSEL_WINDOW = 2
 ADAPT_MIN = 1 << 12
 ADAPT_MAX = 1 << 20
 
-#: Stage fields mirrored into ``deepmap_executor_stage_seconds_total``
-#: and rendered as per-operator child spans under each collect span.
+#: Stage fields mirrored into ``deepmap_executor_stage_seconds_total``.
 _STAGE_FIELDS = (
     ("exist", "exist_s"),
     ("aux_merge", "aux_s"),
@@ -332,9 +331,8 @@ class PlanStream:
         self._cursor = 0
         self._dispatched = 0
         self._dispatched_any = False
-        # (seq, start, rows, target, handle, t_dispatch) per in-flight
-        # morsel — t_dispatch anchors the retroactive device-track span.
-        self._inflight: List[Tuple[int, int, int, int, object, float]] = []
+        # (seq, start, rows, target, handle) per in-flight morsel
+        self._inflight: List[Tuple[int, int, int, int, object]] = []
 
     # ------------------------------------------------------------- state
     @property
@@ -360,7 +358,6 @@ class PlanStream:
             return False
         target = self._morsel_rows
         chunk = self.keys[self._cursor : self._cursor + target]
-        t_dispatch = time.perf_counter()
         try:
             handle = self.store._dispatch_lookup(
                 chunk,
@@ -378,7 +375,7 @@ class PlanStream:
             handle = _FailedDispatch(exc)
         rows = int(chunk.shape[0])
         self._inflight.append(
-            (self._dispatched, self._cursor, rows, target, handle, t_dispatch)
+            (self._dispatched, self._cursor, rows, target, handle)
         )
         self.sizes.append(rows)
         self._cursor += rows
@@ -393,43 +390,40 @@ class PlanStream:
         per-operator time feeds :func:`next_morsel_rows` to resize
         subsequent dispatches (partial tail morsels carry no signal).
 
-        Telemetry is emitted here (never in the hot per-key loops):
-        a retroactive **device-track** span ``infer_dispatch`` covering
-        [dispatch(seq) → collect-start(seq)] — the window in which the
-        morsel's device work ran while the host drained earlier morsels
-        — plus a **host-track** ``collect`` span for the blocking host
-        half, per-operator child spans reconstructed from the morsel's
-        stage timings, and the morsel counters/histograms.
+        The morsel's host half runs under a ``collect`` span (its
+        store-level spans nest under it and inherit its ``morsel``
+        number); the morsel counters/histograms are emitted after it,
+        never in the hot per-key loops.
         """
         if not self._inflight:
             raise RuntimeError("collect_one with no morsel in flight")
-        seq, start, rows, target, handle, t_dispatch = self._inflight.pop(0)
-        t_collect0 = time.perf_counter()
+        seq, start, rows, target, handle = self._inflight.pop(0)
         agg: Optional[Dict[tuple, list]] = None
-        if isinstance(handle, _FailedDispatch):
-            values, exists, match, stats, agg = self._degraded(rows, handle.exc)
-        else:
-            try:
-                if self.agg_below:
-                    agg, stats = self.store._collect_aggregate(
-                        handle, self.plan.group_by, self.plan.aggregates
-                    )
-                    values = {}
-                    exists = np.zeros(0, dtype=bool)
-                    match = None
-                else:
-                    values, exists, match, stats = (
-                        self.store._collect_lookup(handle)
-                    )
-            except Exception as exc:
-                if self.plan.on_error != "partial":
-                    raise
-                # OwnerFailure here means even partial degradation was
-                # impossible inside the store (every owner failed);
-                # degrade the whole morsel at this level instead.
-                values, exists, match, stats, agg = self._degraded(rows, exc)
-        t_collect1 = time.perf_counter()
-        self._emit_morsel(seq, rows, stats, t_dispatch, t_collect0, t_collect1)
+        kind = self.plan.kind
+        with obs.span("collect", morsel=seq, rows=rows, kind=kind) as span:
+            if isinstance(handle, _FailedDispatch):
+                values, exists, match, stats, agg = self._degraded(rows, handle.exc)
+            else:
+                try:
+                    if self.agg_below:
+                        agg, stats = self.store._collect_aggregate(
+                            handle, self.plan.group_by, self.plan.aggregates
+                        )
+                        values = {}
+                        exists = np.zeros(0, dtype=bool)
+                        match = None
+                    else:
+                        values, exists, match, stats = (
+                            self.store._collect_lookup(handle)
+                        )
+                except Exception as exc:
+                    if self.plan.on_error != "partial":
+                        raise
+                    # OwnerFailure here means even partial degradation
+                    # was impossible inside the store (every owner
+                    # failed); degrade the whole morsel at this level.
+                    values, exists, match, stats, agg = self._degraded(rows, exc)
+        self._emit_morsel(rows, stats, span.seconds)
         if not self.fixed and rows == target:
             operator_s = (
                 stats.infer_s + stats.exist_s + stats.aux_s
@@ -437,7 +431,7 @@ class PlanStream:
             )
             self._morsel_rows = next_morsel_rows(target, operator_s)
         if self.done:
-            self._emit_plan(t_collect1)
+            self._emit_plan(span.end)
         return MorselResult(
             index=seq,
             start=start,
@@ -504,34 +498,11 @@ class PlanStream:
         return values, exists, match, stats
 
     # --------------------------------------------------------- telemetry
-    def _emit_morsel(
-        self, seq: int, rows: int, stats: ExplainStats,
-        t_dispatch: float, t_collect0: float, t_collect1: float,
-    ) -> None:
+    def _emit_morsel(self, rows: int, stats: ExplainStats, collect_s: float) -> None:
         reg = obs.registry()
-        trc = obs.tracer()
-        if not (reg.enabled or trc.enabled):
+        if not reg.enabled:
             return
         kind = self.plan.kind
-        trc.add_span(
-            "infer_dispatch", t_dispatch, t_collect0, track="device",
-            morsel=seq, rows=rows, kind=kind,
-        )
-        trc.add_span(
-            "collect", t_collect0, t_collect1, track="host",
-            morsel=seq, rows=rows, kind=kind,
-        )
-        # Operator child spans are a *reconstruction*: the store hooks
-        # report stage durations, not wall endpoints, so the children
-        # are laid out sequentially from collect-start in pipeline
-        # order.  Gaps under the collect span are un-attributed host
-        # overhead (scatter bookkeeping, stats merging).
-        t = t_collect0
-        for op, field in _STAGE_FIELDS:
-            d = getattr(stats, field)
-            if d > 0:
-                trc.add_span(f"op:{op}", t, t + d, track="host", morsel=seq)
-                t += d
         reg.counter(
             "deepmap_executor_morsels_total",
             "Morsels collected, by plan kind.",
@@ -544,7 +515,7 @@ class PlanStream:
         reg.histogram(
             "deepmap_executor_morsel_seconds",
             "Host collect latency per morsel.",
-        ).observe(t_collect1 - t_collect0, kind=kind)
+        ).observe(collect_s, kind=kind)
         stages = reg.counter(
             "deepmap_executor_stage_seconds_total",
             "Cumulative per-operator seconds, from store stage timings.",

@@ -557,8 +557,17 @@ class ExplainStats:
     #: Probe keys scattered into the right store's existence index by
     #: a ``join(...)`` plan (summed across morsels).
     join_probes: int = 0
+    #: Keys probed in ``T_aux`` and partitions visited for them, as
+    #: ``AuxTable.get`` counts them.
+    aux_keys: int = 0
+    aux_visits: int = 0
     route_s: float = 0.0
     infer_s: float = 0.0
+    #: The parts of ``infer_s`` spent in the ``engine.dispatch`` spans
+    #: (featurize, upload, launch) and the ``engine.wait`` spans (the
+    #: host blocked on device outputs and their copy out).
+    dispatch_s: float = 0.0
+    wait_s: float = 0.0
     exist_s: float = 0.0
     aux_s: float = 0.0
     filter_s: float = 0.0
@@ -576,6 +585,8 @@ class ExplainStats:
         widest fan-out seen rather than being dropped."""
         self.route_s += other.route_s
         self.infer_s += other.infer_s
+        self.dispatch_s += other.dispatch_s
+        self.wait_s += other.wait_s
         self.exist_s += other.exist_s
         self.aux_s += other.aux_s
         self.filter_s += other.filter_s
@@ -588,6 +599,8 @@ class ExplainStats:
         self.retries += other.retries
         self.keys_unresolved += other.keys_unresolved
         self.join_probes += other.join_probes
+        self.aux_keys += other.aux_keys
+        self.aux_visits += other.aux_visits
         # one group seen by N morsels is still one group — keep the max
         self.groups_emitted = max(self.groups_emitted, other.groups_emitted)
         self.owners_failed = _union(self.owners_failed, other.owners_failed)

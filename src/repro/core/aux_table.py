@@ -20,6 +20,7 @@ from typing import Dict, Optional, Tuple
 
 import numpy as np
 
+from repro import obs
 from repro.storage import MemoryPool, get_codec
 
 _EMPTY_I64 = np.zeros(0, dtype=np.int64)
@@ -102,20 +103,27 @@ class AuxTable:
     # -- partition access ------------------------------------------------------
     def _load_partition(self, idx: int) -> Tuple[np.ndarray, np.ndarray]:
         def loader():
-            blob = self._codec.decompress(self._partitions[idx])
-            part = _unpack_partition(blob)
+            with obs.span("aux.decompress"):
+                blob = self._codec.decompress(self._partitions[idx])
+                part = _unpack_partition(blob)
             return part, part[0].nbytes + part[1].nbytes
 
         return self.pool.get(("aux", id(self), self._generation, idx), loader)
 
     # -- batched lookup ----------------------------------------------------------
-    def get(self, keys: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
+    def get(self, keys: np.ndarray, stats=None) -> Tuple[np.ndarray, np.ndarray]:
         """Batched aux lookup.
 
         Returns ``(found_mask (n,) bool, codes (n, m) int32)``; rows not
         present in T_aux have arbitrary codes and found=False.  Queries
         are grouped per partition so each partition is decompressed at
         most once per batch (paper §IV-B2).
+
+        Timed by the ``aux.get`` span, whose args carry the keys probed
+        and the partitions visited.  Those counts are taken once per
+        call: into ``deepmap_aux_keys_total{outcome}`` (found or absent)
+        and ``deepmap_aux_partition_visits_total``, and into ``stats``
+        (an :class:`~repro.api.plan.ExplainStats`) when one is given.
         """
         keys = np.asarray(keys, dtype=np.int64)
         n = keys.shape[0]
@@ -123,6 +131,30 @@ class AuxTable:
         out = np.zeros((n, self.num_values), dtype=np.int32)
         if n == 0:
             return found, out
+        with obs.span("aux.get") as span:
+            visits = self._probe(keys, found, out)
+        span.args.update(keys=n, visits=visits)
+        hits = int(np.count_nonzero(found))
+        reg = obs.registry()
+        probed = reg.counter(
+            "deepmap_aux_keys_total", "Keys probed in T_aux, by outcome."
+        )
+        probed.inc(hits, outcome="found")
+        probed.inc(n - hits, outcome="absent")
+        reg.counter(
+            "deepmap_aux_partition_visits_total",
+            "T_aux partitions visited by lookups (one per partition a "
+            "batch hits).",
+        ).inc(visits)
+        if stats is not None:
+            stats.aux_keys += n
+            stats.aux_visits += visits
+        return found, out
+
+    def _probe(self, keys: np.ndarray, found: np.ndarray, out: np.ndarray) -> int:
+        """Fill ``found``/``out`` for ``keys``; returns the partitions
+        visited."""
+        visits = 0
 
         # Overlay first: delta wins over partitions; tombstones kill rows.
         if self._delta:
@@ -147,6 +179,7 @@ class AuxTable:
                 p = rpid[start]
                 while end < ridx.size and rpid[end] == p:
                     end += 1
+                visits += 1
                 pkeys, pcodes = self._load_partition(int(p))
                 qk = keys[ridx[start:end]]
                 pos = np.searchsorted(pkeys, qk)
@@ -157,7 +190,7 @@ class AuxTable:
                 found[sel] = True
                 out[sel] = pcodes[pos[hit]]
                 start = end
-        return found, out
+        return visits
 
     def contains(self, keys: np.ndarray) -> np.ndarray:
         return self.get(keys)[0]

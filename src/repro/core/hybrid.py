@@ -27,6 +27,7 @@ from typing import Dict, Optional, Tuple
 
 import numpy as np
 
+from repro import obs
 from repro.api.plan import ExplainStats, agg_partials, fold_agg_partials
 from repro.api.protocol import MappingStore
 from repro.core import model as model_lib
@@ -431,15 +432,15 @@ class DeepMappingStore(MappingStore):
         if not pending.tickets:
             # Zero keys or empty projection: typed empty/zero columns,
             # host existence only — never reaches JAX.
-            t1 = time.perf_counter()
-            exists = self.vexist.test(keys)
-            t2 = time.perf_counter()
-            values = {
-                t: self.codecs[t].decode(np.zeros(keys.shape[0], dtype=np.int32))
-                for t in decode_cols
-            }
-            stats.exist_s = t2 - t1
-            stats.decode_s = time.perf_counter() - t2
+            with obs.span("store.exist") as exist_span:
+                exists = self.vexist.test(keys)
+            with obs.span("store.decode") as decode_span:
+                values = {
+                    t: self.codecs[t].decode(np.zeros(keys.shape[0], dtype=np.int32))
+                    for t in decode_cols
+                }
+            stats.exist_s = exist_span.seconds
+            stats.decode_s = decode_span.seconds
             return values, exists, exists.copy() if preds else None, stats
 
         task_idx = [all_tasks.index(t) for t in wanted]
@@ -455,67 +456,68 @@ class DeepMappingStore(MappingStore):
                 and pending.next_start < keys.shape[0]
             ):
                 self._dispatch_next_chunk(pending)
-            t1 = time.perf_counter()
-            stats.infer_s += t1 - t0
             pred, exists = self.engine.collect(ticket)      # line 3 (inference)
-            t2 = time.perf_counter()
+            stats.infer_s += time.perf_counter() - t0
+            stats.dispatch_s += ticket.dispatch_s
+            stats.wait_s += ticket.wait_s
             if exists is None:                               # line 5 (existence)
-                exists = self.vexist.test(ticket.keys)
+                with obs.span("store.exist") as span:
+                    exists = self.vexist.test(ticket.keys)
+                stats.exist_s += span.seconds
             t3 = time.perf_counter()
             # line 6-8: aux override for existing keys only.  T_aux rows
             # carry codes for ALL tasks; project to the evaluated ones.
             exist_idx = np.flatnonzero(exists)
-            found, aux_codes = self.aux.get(ticket.keys[exist_idx])
+            found, aux_codes = self.aux.get(ticket.keys[exist_idx], stats)
             pred[exist_idx[found]] = aux_codes[found][:, task_idx]
-            t4 = time.perf_counter()
-            stats.infer_s += t2 - t1
-            stats.exist_s += t3 - t2
-            stats.aux_s += t4 - t3
+            stats.aux_s += time.perf_counter() - t3
             # Predicate filter on aux-corrected argmax codes: one
             # boolean gather per predicate, BEFORE any decode.
             if preds:
-                if ticket.match is not None:
-                    # In-kernel filter: the fused kernel already ANDed
-                    # the predicate code tables over the model codes and
-                    # exist bits; only the (few) aux-overridden rows can
-                    # have changed codes, so re-evaluate just those on
-                    # their corrected codes via the full host tables.
-                    match = ticket.match
-                    aux_rows = exist_idx[found]
-                    if aux_rows.size:
-                        patched = np.ones(aux_rows.shape[0], dtype=bool)
+                with obs.span("store.filter") as span:
+                    if ticket.match is not None:
+                        # In-kernel filter: the fused kernel already
+                        # ANDed the predicate code tables over the model
+                        # codes and exist bits; only the (few)
+                        # aux-overridden rows can have changed codes, so
+                        # re-evaluate just those on their corrected codes
+                        # via the full host tables.
+                        match = ticket.match
+                        aux_rows = exist_idx[found]
+                        if aux_rows.size:
+                            patched = np.ones(aux_rows.shape[0], dtype=bool)
+                            for wi, table, _ in preds:
+                                patched &= table[pred[aux_rows, wi]]
+                            match[aux_rows] = patched
+                    else:
+                        stats.kernel_filtered = False
+                        match = exists.copy()
                         for wi, table, _ in preds:
-                            patched &= table[pred[aux_rows, wi]]
-                        match[aux_rows] = patched
-                else:
-                    stats.kernel_filtered = False
-                    match = exists.copy()
-                    for wi, table, _ in preds:
-                        codes_w = np.where(exists, pred[:, wi], 0)
-                        match &= table[codes_w]
-                hit = np.flatnonzero(match)
-                t5 = time.perf_counter()
-                stats.filter_s += t5 - t4
+                            codes_w = np.where(exists, pred[:, wi], 0)
+                            match &= table[codes_w]
+                    hit = np.flatnonzero(match)
+                stats.filter_s += span.seconds
                 stats.rows_matched += int(hit.size)
                 # line 13: decode ONLY the matching rows.
-                for t, wi in zip(decode_cols, dec_idx):
-                    codec = self.codecs[t]
-                    out = np.zeros(
-                        exists.shape[0], dtype=codec.decode_map.dtype
-                    )
-                    if hit.size:
-                        out[hit] = codec.decode(pred[hit, wi])
-                    value_parts[t].append(out)
+                with obs.span("store.decode") as span:
+                    for t, wi in zip(decode_cols, dec_idx):
+                        codec = self.codecs[t]
+                        out = np.zeros(
+                            exists.shape[0], dtype=codec.decode_map.dtype
+                        )
+                        if hit.size:
+                            out[hit] = codec.decode(pred[hit, wi])
+                        value_parts[t].append(out)
                 stats.rows_decoded += int(hit.size)
-                stats.decode_s += time.perf_counter() - t5
                 match_parts.append(match)
             else:
                 # line 13: decode — selected columns only.
-                for t, wi in zip(decode_cols, dec_idx):
-                    safe = np.where(exists, pred[:, wi], 0)
-                    value_parts[t].append(self.codecs[t].decode(safe))
+                with obs.span("store.decode") as span:
+                    for t, wi in zip(decode_cols, dec_idx):
+                        safe = np.where(exists, pred[:, wi], 0)
+                        value_parts[t].append(self.codecs[t].decode(safe))
                 stats.rows_decoded += int(exists.shape[0])
-                stats.decode_s += time.perf_counter() - t4
+            stats.decode_s += span.seconds
             exists_parts.append(exists)
 
         exists = (
@@ -555,37 +557,38 @@ class DeepMappingStore(MappingStore):
                 and pending.next_start < keys.shape[0]
             ):
                 self._dispatch_next_chunk(pending)
-            t1 = time.perf_counter()
             codes, exists = self.engine.collect(ticket)
-            t2 = time.perf_counter()
+            stats.infer_s += time.perf_counter() - t0
+            stats.dispatch_s += ticket.dispatch_s
+            stats.wait_s += ticket.wait_s
             if exists is None:
-                exists = self.vexist.test(ticket.keys)
+                with obs.span("store.exist") as span:
+                    exists = self.vexist.test(ticket.keys)
+                stats.exist_s += span.seconds
             t3 = time.perf_counter()
             exist_idx = np.flatnonzero(exists)
-            found, aux_codes = self.aux.get(ticket.keys[exist_idx])
+            found, aux_codes = self.aux.get(ticket.keys[exist_idx], stats)
             task_idx = [self.spec.tasks.index(t) for t in pending.wanted]
             codes[exist_idx[found]] = aux_codes[found][:, task_idx]
-            t4 = time.perf_counter()
-            stats.infer_s += (t1 - t0) + (t2 - t1)
-            stats.exist_s += t3 - t2
-            stats.aux_s += t4 - t3
+            stats.aux_s += time.perf_counter() - t3
             match = None
             if preds:
-                if ticket.match is not None:
-                    match = ticket.match
-                    aux_rows = exist_idx[found]
-                    if aux_rows.size:
-                        patched = np.ones(aux_rows.shape[0], dtype=bool)
+                with obs.span("store.filter") as span:
+                    if ticket.match is not None:
+                        match = ticket.match
+                        aux_rows = exist_idx[found]
+                        if aux_rows.size:
+                            patched = np.ones(aux_rows.shape[0], dtype=bool)
+                            for wi, table, _ in preds:
+                                patched &= table[codes[aux_rows, wi]]
+                            match[aux_rows] = patched
+                    else:
+                        stats.kernel_filtered = False
+                        match = exists.copy()
                         for wi, table, _ in preds:
-                            patched &= table[codes[aux_rows, wi]]
-                        match[aux_rows] = patched
-                else:
-                    stats.kernel_filtered = False
-                    match = exists.copy()
-                    for wi, table, _ in preds:
-                        codes_w = np.where(exists, codes[:, wi], 0)
-                        match &= table[codes_w]
-                stats.filter_s += time.perf_counter() - t4
+                            codes_w = np.where(exists, codes[:, wi], 0)
+                            match &= table[codes_w]
+                stats.filter_s += span.seconds
                 stats.rows_matched += int(match.sum())
             yield codes, exists, match
 
@@ -646,42 +649,42 @@ class DeepMappingStore(MappingStore):
 
         def fold(codes: Optional[np.ndarray], sel: np.ndarray) -> None:
             """Fold one chunk's selected rows (code-space) into state."""
-            t5 = time.perf_counter()
-            if sel.size:
-                if gidx:
-                    if len(gidx) > 1:
-                        packed = np.ravel_multi_index(
-                            [codes[sel, wi] for wi in gidx], gdims
-                        )
+            with obs.span("exec.agg") as span:
+                if sel.size:
+                    if gidx:
+                        if len(gidx) > 1:
+                            packed = np.ravel_multi_index(
+                                [codes[sel, wi] for wi in gidx], gdims
+                            )
+                        else:
+                            packed = codes[sel, gidx[0]]
+                        ug, ginv = np.unique(packed, return_inverse=True)
+                        coords = np.unravel_index(ug, gdims)
+                        # decode per DISTINCT group, not per row: this is
+                        # label materialization, not row decode
+                        labels = [
+                            self.codecs[c].decode(np.asarray(coord)).tolist()
+                            for c, coord in zip(group_by, coords)
+                        ]
+                        group_tuples = list(zip(*labels))
                     else:
-                        packed = codes[sel, gidx[0]]
-                    ug, ginv = np.unique(packed, return_inverse=True)
-                    coords = np.unravel_index(ug, gdims)
-                    # decode per DISTINCT group, not per row: this is
-                    # label materialization, not row decode
-                    labels = [
-                        self.codecs[c].decode(np.asarray(coord)).tolist()
-                        for c, coord in zip(group_by, coords)
+                        ug = np.zeros(1, dtype=np.int64)
+                        ginv = np.zeros(sel.size, dtype=np.int64)
+                        group_tuples = [()]
+                    value_arrays = [
+                        None if table is None else table[codes[sel, wi]]
+                        for wi, table in specs
                     ]
-                    group_tuples = list(zip(*labels))
-                else:
-                    ug = np.zeros(1, dtype=np.int64)
-                    ginv = np.zeros(sel.size, dtype=np.int64)
-                    group_tuples = [()]
-                value_arrays = [
-                    None if table is None else table[codes[sel, wi]]
-                    for wi, table in specs
-                ]
-                partials = agg_partials(aggregates, ginv, len(ug), value_arrays)
-                fold_agg_partials(state, group_tuples, aggregates, partials)
-            stats.agg_s += time.perf_counter() - t5
+                    partials = agg_partials(aggregates, ginv, len(ug), value_arrays)
+                    fold_agg_partials(state, group_tuples, aggregates, partials)
+            stats.agg_s += span.seconds
 
         if not pending.tickets:
             # Zero keys, or a count-only global aggregate with no
             # predicate heads: host existence test answers everything.
-            t1 = time.perf_counter()
-            exists = self.vexist.test(keys)
-            stats.exist_s = time.perf_counter() - t1
+            with obs.span("store.exist") as span:
+                exists = self.vexist.test(keys)
+            stats.exist_s = span.seconds
             fold(None, np.flatnonzero(exists))
             return state, stats
 

@@ -73,6 +73,10 @@ from repro.kernels import ops as kops
 
 INT32_MAX = 2**31 - 1
 
+# Every enabled ``with``-span of repro.obs also lands on the profiler's
+# host plane, on the clock of the device's ops.
+obs.set_profiler_hook(jax.profiler.TraceAnnotation)
+
 #: Host work trails device dispatch by this many in-flight chunks.
 PIPELINE_DEPTH = 2
 
@@ -201,6 +205,9 @@ class InferTicket:
     #: the host filter instead).  Aux-overridden rows still need the
     #: host patch: the kernel matched on the *model* code.
     match: Optional[np.ndarray] = None
+    #: Seconds of the ``engine.dispatch`` and ``engine.wait`` spans.
+    dispatch_s: float = 0.0
+    wait_s: float = 0.0
 
 
 class InferenceEngine:
@@ -393,36 +400,38 @@ class InferenceEngine:
         if n == 0 or not tasks:
             return InferTicket(n=n, tasks=tasks, path="empty", keys=keys,
                                want_exists=want_exists)
-        # Fault-injection site: after the zero-length early-out so the
-        # executor's typed-empty probes (used to build placeholder
-        # columns in degraded mode) are never themselves failed.
-        fault_injection.maybe_fail("engine_dispatch")
-        self.stats.bump("dispatches")
-        # MLPSpec canonicalizes task order, so the subset entry (and the
-        # device result columns) follow spec order; collect() permutes
-        # back to the requested order.
-        canon = tuple(t for t in self.spec.tasks if t in frozenset(tasks))
-        entry = self._entry(canon)
-        bucket = self._bucket(n)
+        with obs.span("engine.dispatch") as span:
+            # Fault-injection site: after the zero-length early-out so
+            # the executor's typed-empty probes (used to build
+            # placeholder columns in degraded mode) are never failed.
+            fault_injection.maybe_fail("engine_dispatch")
+            self.stats.bump("dispatches")
+            # MLPSpec canonicalizes task order, so the subset entry (and
+            # the device result columns) follow spec order; collect()
+            # permutes back to the requested order.
+            canon = tuple(t for t in self.spec.tasks if t in frozenset(tasks))
+            entry = self._entry(canon)
+            bucket = self._bucket(n)
 
-        if self.use_pallas and want_exists and self._fused_eligible(entry):
-            ticket = self._dispatch_fused(keys, tasks, entry, bucket,
-                                          pred_tables)
-        elif self.use_pallas and self._pallas_eligible(entry):
-            ticket = self._dispatch_pallas_digits(keys, tasks, entry, bucket,
-                                                  want_exists)
-        elif self.use_pallas and (
-            plan := self._streamed_plan(entry, want_exists)
-        ) is not None:
-            ticket = self._dispatch_fused_streamed(keys, tasks, entry, bucket,
-                                                   *plan)
-        elif self.encoder.capacity <= INT32_MAX:
-            ticket = self._dispatch_jit_keys(keys, tasks, entry, bucket,
-                                             want_exists)
-        else:
-            ticket = self._dispatch_jit_digits(keys, tasks, entry, bucket,
-                                               want_exists)
-        ticket.task_order = entry.spec.tasks
+            if self.use_pallas and want_exists and self._fused_eligible(entry):
+                ticket = self._dispatch_fused(keys, tasks, entry, bucket,
+                                              pred_tables)
+            elif self.use_pallas and self._pallas_eligible(entry):
+                ticket = self._dispatch_pallas_digits(keys, tasks, entry, bucket,
+                                                      want_exists)
+            elif self.use_pallas and (
+                plan := self._streamed_plan(entry, want_exists)
+            ) is not None:
+                ticket = self._dispatch_fused_streamed(keys, tasks, entry, bucket,
+                                                       *plan)
+            elif self.encoder.capacity <= INT32_MAX:
+                ticket = self._dispatch_jit_keys(keys, tasks, entry, bucket,
+                                                 want_exists)
+            else:
+                ticket = self._dispatch_jit_digits(keys, tasks, entry, bucket,
+                                                   want_exists)
+            ticket.task_order = entry.spec.tasks
+        ticket.dispatch_s = span.seconds
         return ticket
 
     def _keys_i32(self, keys: np.ndarray, bucket: int) -> np.ndarray:
@@ -581,31 +590,33 @@ class InferenceEngine:
         if ticket.path == "empty":
             return np.zeros((n, len(ticket.tasks)), dtype=np.int32), None
 
-        if ticket.path == "pallas_digits":
-            codes = np.concatenate(
-                [np.asarray(o)[:n] for o in ticket.codes_dev], axis=1
-            )
-        elif ticket.path == "fused_streamed":
-            # one (n_pad, page_tasks) block per page, spec order overall
-            codes = np.concatenate(
-                [np.asarray(c)[:n] for c in ticket.codes_dev], axis=1
-            )
-        else:
-            codes = np.asarray(ticket.codes_dev)[:n]
-        if ticket.task_order and ticket.tasks != ticket.task_order:
-            # requested projection order differs from spec canonical
-            perm = [ticket.task_order.index(t) for t in ticket.tasks]
-            codes = codes[:, perm]
-        if not codes.flags.writeable:
-            codes = codes.copy()  # callers patch the aux override in place
-        if ticket.in_cap is not None and not ticket.in_cap.all():
-            codes[~ticket.in_cap] = 0
+        with obs.span("engine.wait") as span:
+            if ticket.path == "pallas_digits":
+                codes = np.concatenate(
+                    [np.asarray(o)[:n] for o in ticket.codes_dev], axis=1
+                )
+            elif ticket.path == "fused_streamed":
+                # one (n_pad, page_tasks) block per page, spec order overall
+                codes = np.concatenate(
+                    [np.asarray(c)[:n] for c in ticket.codes_dev], axis=1
+                )
+            else:
+                codes = np.asarray(ticket.codes_dev)[:n]
+            if ticket.task_order and ticket.tasks != ticket.task_order:
+                # requested projection order differs from spec canonical
+                perm = [ticket.task_order.index(t) for t in ticket.tasks]
+                codes = codes[:, perm]
+            if not codes.flags.writeable:
+                codes = codes.copy()  # callers patch the aux override in place
+            if ticket.in_cap is not None and not ticket.in_cap.all():
+                codes[~ticket.in_cap] = 0
 
-        exists = None
-        if ticket.exists_dev is not None:
-            exists = np.asarray(ticket.exists_dev)[:n].astype(bool)
-        if ticket.match_dev is not None:
-            ticket.match = np.asarray(ticket.match_dev)[:n].astype(bool)
+            exists = None
+            if ticket.exists_dev is not None:
+                exists = np.asarray(ticket.exists_dev)[:n].astype(bool)
+            if ticket.match_dev is not None:
+                ticket.match = np.asarray(ticket.match_dev)[:n].astype(bool)
+        ticket.wait_s = span.seconds
         return codes, exists
 
     # ------------------------------------------------------- convenience

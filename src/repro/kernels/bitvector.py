@@ -65,4 +65,5 @@ def bitvector_call(
         out_specs=pl.BlockSpec((tile_n,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((n,), jnp.int32),
         interpret=interpret,
+        name="bitvector_test",
     )(keys, words)

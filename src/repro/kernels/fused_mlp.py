@@ -212,6 +212,7 @@ def fused_mlp_call(
         out_shape=out_shapes,
         compiler_params=_compiler_params(vmem_limit_bytes),
         interpret=interpret,
+        name="fused_mlp",
     )(digits, *flat_weights)
 
 
@@ -335,6 +336,7 @@ def fused_lookup_call(
         out_shape=[jax.ShapeDtypeStruct((n, 1), jnp.int32) for _ in spec.tasks],
         compiler_params=_compiler_params(vmem_limit_bytes),
         interpret=interpret,
+        name="fused_lookup",
     )(keys[:, None], *flat_weights)
     # Out-of-capacity keys answer code 0 (the ``_infer_codes`` contract).
     in_cap = (keys >= 0) & (keys < capacity)

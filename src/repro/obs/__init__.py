@@ -9,14 +9,19 @@ Quick tour::
     from repro import obs
 
     obs.counter("deepmap_executor_morsels_total").inc(kind="scan")
-    with obs.span("collect", track="host", morsel=0):
+    with obs.span("collect", morsel=0) as sp:
         ...                                  # timed work
+    print(sp.seconds)                        # measured even when disabled
     print(obs.to_prometheus())               # /metrics scrape body
     obs.write_chrome_trace("trace.json")     # open in Perfetto
 
 ``obs.set_enabled(False)`` flips both the registry and tracer to
 no-ops in one call — used by the benchmarks to measure the always-on
 overhead (<3% QPS budget, recorded in BENCH_lookup.json).
+
+``with``-spans are mirrored into the JAX profiler once a module that
+imports JAX installs the annotation factory (:func:`set_profiler_hook`);
+:data:`PROFILER_SPANS` lists the ones the served path opens.
 """
 
 from repro.obs.export import (
@@ -37,7 +42,14 @@ from repro.obs.metrics import (
     registry,
     set_registry,
 )
-from repro.obs.tracing import Span, Tracer, set_tracer, tracer
+from repro.obs.tracing import (
+    PROFILER_SPANS,
+    Span,
+    Tracer,
+    set_profiler_hook,
+    set_tracer,
+    tracer,
+)
 
 __all__ = [
     "Counter",
@@ -45,6 +57,7 @@ __all__ = [
     "Histogram",
     "LATENCY_BUCKETS",
     "MetricsRegistry",
+    "PROFILER_SPANS",
     "SIZE_BUCKETS",
     "Span",
     "Tracer",
@@ -53,6 +66,7 @@ __all__ = [
     "histogram",
     "registry",
     "set_enabled",
+    "set_profiler_hook",
     "set_registry",
     "set_tracer",
     "snapshot",
@@ -83,7 +97,8 @@ def histogram(name: str, help: str = "", buckets=None) -> Histogram:
 
 
 def span(name: str, track: str = "host", **args):
-    """``tracer().span(...)`` on the current default tracer."""
+    """``tracer().span(...)`` on the current default tracer: a context
+    manager whose ``seconds`` is the block's duration."""
     return tracer().span(name, track=track, **args)
 
 

@@ -10,10 +10,11 @@ drop a ``trace.json`` for Perfetto — without three bookkeeping paths.
 Chrome trace format notes: each span becomes one complete ("X") event
 with ``ts``/``dur`` in microseconds; each logical track (see
 :mod:`repro.obs.tracing`) becomes a tid under one pid, named via "M"
-(metadata) ``thread_name`` events so Perfetto shows "device" and
-"host" as labeled rows.  Open ``trace.json`` at https://ui.perfetto.dev
-(or chrome://tracing) — the dispatch/collect pipeline overlap shows up
-as device-track spans covering the host-track spans beneath them.
+(metadata) ``thread_name`` events so Perfetto shows "host", "plans"
+and "shards" as labeled rows.  Open ``trace.json`` at
+https://ui.perfetto.dev (or chrome://tracing).  What the device did
+is in the JAX profiler's trace, where the same ``with``-spans appear on
+the host plane (:func:`repro.obs.set_profiler_hook`).
 """
 
 from __future__ import annotations
@@ -92,8 +93,7 @@ def to_chrome_trace(
 
     Timestamps are rebased so the oldest recorded span starts at 0 µs
     (perf_counter's epoch is arbitrary).  Track → tid assignment is
-    first-seen order, with "device" pinned to tid 0 when present so
-    the async device row renders above the host rows in Perfetto.
+    first-seen order; a span's parent rides in its args.
     """
     trc = trc if trc is not None else default_tracer()
     spans = trc.spans()
@@ -111,8 +111,6 @@ def to_chrome_trace(
 
     t0 = min(s.start for s in spans)
     tracks: Dict[str, int] = {}
-    if any(s.track == "device" for s in spans):
-        tracks["device"] = 0
     for s in spans:
         if s.track not in tracks:
             tracks[s.track] = len(tracks)
@@ -127,6 +125,9 @@ def to_chrome_trace(
             }
         )
     for s in spans:
+        args = {k: str(v) for k, v in s.args.items()}
+        if s.parent:
+            args["parent"] = s.parent
         events.append(
             {
                 "name": s.name,
@@ -136,7 +137,7 @@ def to_chrome_trace(
                 "tid": tracks[s.track],
                 "ts": (s.start - t0) * 1e6,
                 "dur": s.duration * 1e6,
-                "args": {k: str(v) for k, v in s.args.items()},
+                "args": args,
             }
         )
     return {"traceEvents": events, "displayTimeUnit": "ms"}

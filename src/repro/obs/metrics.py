@@ -156,8 +156,9 @@ class Histogram(_Metric):
         self.buckets = bounds
         self._states: Dict[_LabelKey, _HistState] = {}  # guarded-by: _lock
 
-    def observe(self, value: float, **labels) -> None:
-        """Record one observation into the labeled child."""
+    def observe(self, value: float, times: int = 1, **labels) -> None:
+        """Record ``times`` observations of ``value`` into the labeled
+        child."""
         if not self._registry.enabled:
             return
         key = _label_key(labels)
@@ -166,9 +167,9 @@ class Histogram(_Metric):
             state = self._states.get(key)
             if state is None:
                 state = self._states[key] = _HistState(len(self.buckets))
-            state.counts[idx] += 1
-            state.sum += value
-            state.count += 1
+            state.counts[idx] += times
+            state.sum += value * times
+            state.count += times
 
     # ------------------------------------------------------------- reading
     def state(self, **labels) -> Optional[_HistState]:

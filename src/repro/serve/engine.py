@@ -35,23 +35,32 @@ class ServeStats:
     """Serving-side rollup of the SAME stage accounting the executor
     produces (per-morsel ``ExplainStats`` plus the plan stream's
     route/cache evidence) — not an independently-measured field set.
-    The full pipeline is covered: route (key-source/plan compile),
-    infer/exist/aux/decode from the store hooks, filter (zero unless a
-    predicate plan is served), gather (scatter-back to requesters).
-    Everything here is also mirrored into the process metrics registry
-    under ``deepmap_serve_*`` for export."""
+    The full pipeline is covered: merge (concatenate + dedup, the
+    ``serve.merge`` span), route (key-source/plan compile),
+    infer/exist/aux/decode from the store hooks — ``dispatch_s`` and
+    ``wait_s`` are the engine's spans inside ``infer_s``, ``aux_keys``
+    and ``aux_visits`` the ``T_aux`` probe counts — filter (zero unless
+    a predicate plan is served), gather (the ``serve.scatter`` span
+    back to requesters).  Requests, keys and latencies are also
+    mirrored into the process metrics registry under
+    ``deepmap_serve_*`` for export."""
 
     requests: int = 0
     keys: int = 0
     batches: int = 0
     total_s: float = 0.0
+    merge_s: float = 0.0
     route_s: float = 0.0
     infer_s: float = 0.0
+    dispatch_s: float = 0.0
+    wait_s: float = 0.0
     exist_s: float = 0.0
     aux_s: float = 0.0
     filter_s: float = 0.0
     decode_s: float = 0.0
     gather_s: float = 0.0
+    aux_keys: int = 0
+    aux_visits: int = 0
     cache_hits: int = 0
     cache_misses: int = 0
     cache_bypass: int = 0
@@ -111,8 +120,10 @@ class LookupServer:
         )
         depth.inc(len(requests))
         lens = [len(r) for r in requests]
-        merged = np.concatenate([np.asarray(r, dtype=np.int64) for r in requests])
-        uniq, inverse = np.unique(merged, return_inverse=True)  # sorted + dedup
+        with obs.span("serve.merge") as merge:
+            merged = np.concatenate([np.asarray(r, dtype=np.int64) for r in requests])
+            uniq, inverse = np.unique(merged, return_inverse=True)  # sorted + dedup
+        self.stats.merge_s += merge.seconds
 
         # One point plan over the merged uniques, morselized at the
         # server's batch size.  Columns pass straight through so
@@ -145,8 +156,12 @@ class LookupServer:
                 chunks.setdefault(c, []).append(arr)
             self.stats.batches += 1
             self.stats.infer_s += morsel.stats.infer_s
+            self.stats.dispatch_s += morsel.stats.dispatch_s
+            self.stats.wait_s += morsel.stats.wait_s
             self.stats.exist_s += morsel.stats.exist_s
             self.stats.aux_s += morsel.stats.aux_s
+            self.stats.aux_keys += morsel.stats.aux_keys
+            self.stats.aux_visits += morsel.stats.aux_visits
             self.stats.filter_s += morsel.stats.filter_s
             self.stats.decode_s += morsel.stats.decode_s
         self.stats.route_s += run.route_s
@@ -161,17 +176,15 @@ class LookupServer:
         # a baseline store's int placeholder chunk before a string
         # chunk — promote instead of crashing or truncating; then
         # scatter back to requesters.
-        t_gather = time.perf_counter()
-        vals_u = {c: np.concatenate(parts) for c, parts in chunks.items()}
-
-        out: List[Tuple[Dict[str, np.ndarray], np.ndarray]] = []
-        off = 0
-        for n in lens:
-            sel = inverse[off : off + n]
-            out.append(({c: a[sel] for c, a in vals_u.items()}, exists_u[sel]))
-            off += n
-        elapsed_gather = time.perf_counter() - t_gather
-        self.stats.gather_s += elapsed_gather
+        with obs.span("serve.scatter") as scatter:
+            vals_u = {c: np.concatenate(parts) for c, parts in chunks.items()}
+            out: List[Tuple[Dict[str, np.ndarray], np.ndarray]] = []
+            off = 0
+            for n in lens:
+                sel = inverse[off : off + n]
+                out.append(({c: a[sel] for c, a in vals_u.items()}, exists_u[sel]))
+                off += n
+        self.stats.gather_s += scatter.seconds
         self.stats.requests += len(requests)
         self.stats.keys += int(sum(lens))
         elapsed = time.perf_counter() - t0
@@ -188,11 +201,9 @@ class LookupServer:
             "Unique keys per merged device batch.",
             buckets=obs.SIZE_BUCKETS,
         ).observe(int(uniq.shape[0]))
-        lat = reg.histogram(
+        reg.histogram(
             "deepmap_serve_request_seconds",
             "Per-request latency (each merged request observes the "
             "merged batch's wall time — the caller-visible latency).",
-        )
-        for _ in range(len(requests)):
-            lat.observe(elapsed)
+        ).observe(elapsed, times=len(requests))
         return out

@@ -111,3 +111,57 @@ class TestAuxTable:
         found, _ = aux.get(np.array([1, 2, 3]))
         assert not found.any()
         assert aux.size_bytes() >= 0
+
+
+class TestAuxCounters:
+    """``AuxTable.get`` counts once per call: keys probed by outcome and
+    partitions visited, into the registry and the caller's stats."""
+
+    @pytest.fixture()
+    def reg(self):
+        from repro import obs
+
+        fresh = obs.MetricsRegistry()
+        prev = obs.set_registry(fresh)
+        yield fresh
+        obs.set_registry(prev)
+
+    def test_counts_exact_on_a_hand_built_table(self, reg):
+        from repro.api.plan import ExplainStats
+
+        # 20-byte rows in 100-byte partitions: 5 rows each, keys 0,10,..,190
+        keys = np.arange(0, 200, 10, dtype=np.int64)
+        codes = np.arange(40, dtype=np.int32).reshape(20, 2)
+        aux = AuxTable.build(keys, codes, codec="none", partition_bytes=100)
+        assert len(aux._partitions) == 4
+        # partition 0 three times, partition 2 once, an absent key inside
+        # partition 3, and a key below the first boundary (no partition)
+        probe = np.array([0, 40, 100, 155, -5, 10], dtype=np.int64)
+        stats = ExplainStats()
+        found, _ = aux.get(probe, stats)
+        assert found.tolist() == [True, True, True, False, False, True]
+        visits = len({int(p) for p in np.searchsorted(keys[::5], probe, "right") - 1
+                      if p >= 0})
+        assert visits == 3
+        assert (stats.aux_keys, stats.aux_visits) == (6, 3)
+        probed = reg.counter("deepmap_aux_keys_total")
+        assert probed.value(outcome="found") == 4
+        assert probed.value(outcome="absent") == 2
+        assert probed.value(outcome="found") + probed.value(outcome="absent") == probe.size
+        assert reg.counter("deepmap_aux_partition_visits_total").value() == visits
+
+    def test_decompress_span_only_on_pool_misses(self):
+        from repro import obs
+
+        trc = obs.Tracer()
+        prev = obs.set_tracer(trc)
+        try:
+            keys, _, aux = make_aux(partition_bytes=256)
+            aux.get(keys[:3])
+            aux.get(keys[:3])
+        finally:
+            obs.set_tracer(prev)
+        assert len(trc.spans("aux.get")) == 2
+        (miss,) = trc.spans("aux.decompress")
+        assert miss.parent == "aux.get"
+        assert (aux.pool.hits, aux.pool.misses) == (1, 1)

@@ -112,6 +112,9 @@ def _compile_lookup(spec, sharding, vmem_limit=None, **kw):
 def test_fused_lookup_with_exists_and_predicates(one_chip):
     text = _compile_lookup(_spec(), one_chip, n_preds=2).as_text()
     assert "tpu_custom_call" in text
+    # the module keeps the jitted name; the kernel's op takes its own
+    assert text.startswith("HloModule jit_fused_lookup_call,")
+    assert "%fused_lookup.1 = " in text
 
 
 def test_fused_lookup_codes_only(one_chip):
@@ -133,7 +136,8 @@ def _compile_mlp_codes(spec, sharding, vmem_limit=None):
 
 def test_fused_mlp_codes(one_chip):
     """The ``pallas_digits`` tier."""
-    assert "tpu_custom_call" in _compile_mlp_codes(_spec(), one_chip)
+    text = _compile_mlp_codes(_spec(), one_chip)
+    assert "tpu_custom_call" in text and "%fused_mlp" in text
 
 
 def _largest_admitted(grow):

@@ -228,6 +228,28 @@ class TestFusedLookupConformance:
             eng.infer(rng.integers(0, 10000, size=int(n)).astype(np.int64))
         assert eng.stats.compiles <= 8, eng.stats.compiles
 
+    def test_module_names_the_roofline_readers_key_on(self):
+        """The benchmark sums device time per XLA module by name: the
+        jitted functions keep theirs whatever the kernels are named."""
+        from repro.core.inference import _codes_from_keys_jit
+        from repro.kernels import bitvector as bv_kernel
+        from repro.kernels import fused_mlp as fm
+        from repro.kernels import ops as kops
+
+        enc, spec, params, bv = make_lookup_setup()
+        keys = jnp.zeros(self.TILE, jnp.int32)
+        flat, _ = kops.pad_flat_weights(params, spec)
+        words = jnp.asarray(bv_kernel.pack_words32(bv.words))
+        fused = fm.fused_lookup_call.lower(
+            keys, words, tuple(flat), spec, self.TILE, kops.LANE,
+            tuple(enc.position_ops()), enc.capacity, True,
+        ).as_text()
+        assert "module @jit_fused_lookup_call" in fused
+        jit_tier = _codes_from_keys_jit.lower(
+            params, keys, spec, tuple(enc.position_ops()), enc.capacity,
+        ).as_text()
+        assert "module @jit__codes_from_keys_jit" in jit_tier
+
     def test_weight_cache_reused_across_calls(self):
         enc, spec, params, bv = make_lookup_setup()
         eng = self._engine(enc, spec, params, bv, use_pallas=True)

@@ -3,6 +3,7 @@ PlanCache thread-safety regression, and the cross-layer invariants
 suite (operator sums, monotone snapshots, trace round-trips, and the
 dispatch/collect pipeline-overlap smoke test on a real hybrid store)."""
 
+import contextlib
 import json
 import threading
 
@@ -156,6 +157,71 @@ class TestTracer:
                 raise RuntimeError()
         assert len(trc.spans("boom")) == 1
 
+    def test_span_records_parent_and_inherits_morsel(self, fresh_obs):
+        _, trc = fresh_obs
+        with trc.span("collect", morsel=7):
+            with trc.span("aux.get"):
+                with trc.span("aux.decompress"):
+                    pass
+        with trc.span("serve.merge"):
+            pass
+        by_name = {s.name: s for s in trc.spans()}
+        assert by_name["collect"].parent == ""
+        assert by_name["aux.get"].parent == "collect"
+        assert by_name["aux.decompress"].parent == "aux.get"
+        assert by_name["aux.decompress"].args["morsel"] == 7
+        assert by_name["serve.merge"].parent == ""
+        assert "morsel" not in by_name["serve.merge"].args
+
+    def test_span_mirrors_name_only_into_profiler_hook(self, fresh_obs):
+        _, trc = fresh_obs
+        opened = []
+
+        class Annotation:
+            def __init__(self, *args, **kwargs):
+                opened.append((args, kwargs))
+
+            def __enter__(self):
+                return self
+
+            def __exit__(self, *exc):
+                opened.append("exit")
+
+        prev = obs.set_profiler_hook(Annotation)
+        try:
+            with trc.span("engine.wait", morsel=2):
+                pass
+            trc.add_span("plan", 0.0, 1.0, track="plans")
+        finally:
+            obs.set_profiler_hook(prev)
+        assert opened == [(("engine.wait",), {}), "exit"]
+
+    @pytest.mark.parametrize("hook", [True, False])
+    def test_disabled_tracer_mirrors_nothing(self, fresh_obs, hook):
+        _, trc = fresh_obs
+        opened = []
+        prev = obs.set_profiler_hook(
+            (lambda name: opened.append(name) or contextlib.nullcontext())
+            if hook else None
+        )
+        try:
+            trc.enabled = not hook   # hooked but disabled, or enabled but unhooked
+            with trc.span("aux.get") as sp:
+                pass
+        finally:
+            obs.set_profiler_hook(prev)
+        assert opened == []
+        assert len(trc) == (0 if hook else 1)
+        assert sp.seconds >= 0.0 and sp.end >= sp.start > 0.0
+
+    def test_histogram_observes_many_at_once(self, fresh_obs):
+        reg, _ = fresh_obs
+        h = reg.histogram("lat_seconds")
+        h.observe(0.01, times=3, kind="a")
+        h.observe(0.02, kind="a")
+        state = h.state(kind="a")
+        assert state.count == 4 and state.sum == pytest.approx(0.05)
+
 
 class TestExporters:
     def test_prometheus_text_format(self, fresh_obs):
@@ -183,17 +249,27 @@ class TestExporters:
 
     def test_chrome_trace_round_trips_and_names_tracks(self, fresh_obs):
         _, trc = fresh_obs
-        trc.add_span("infer_dispatch", 1.0, 2.0, track="device", morsel=0)
+        trc.add_span("plan", 1.0, 2.0, track="plans", kind="scan")
         trc.add_span("collect", 1.5, 1.8, track="host", morsel=0)
         doc = json.loads(json.dumps(obs.to_chrome_trace(trc)))
         events = doc["traceEvents"]
         names = {e["args"]["name"] for e in events if e["ph"] == "M"}
-        assert {"device", "host"} <= names
+        assert {"plans", "host"} <= names
         xs = [e for e in events if e["ph"] == "X"]
         assert len(xs) == 2
-        # device pinned to tid 0; timestamps rebased to 0 in µs
-        dev = next(e for e in xs if e["cat"] == "device")
-        assert dev["tid"] == 0 and dev["ts"] == 0.0 and dev["dur"] == 1e6
+        # tracks get tids in first-seen order; timestamps rebased to 0 in µs
+        plan = next(e for e in xs if e["cat"] == "plans")
+        assert plan["tid"] == 0 and plan["ts"] == 0.0 and plan["dur"] == 1e6
+        assert plan["args"] == {"kind": "scan"}
+
+    def test_chrome_trace_carries_span_parent(self, fresh_obs):
+        _, trc = fresh_obs
+        with trc.span("collect", morsel=1):
+            with trc.span("engine.wait"):
+                pass
+        xs = [e for e in obs.to_chrome_trace(trc)["traceEvents"] if e["ph"] == "X"]
+        wait = next(e for e in xs if e["name"] == "engine.wait")
+        assert wait["args"] == {"morsel": "1", "parent": "collect"}
 
     def test_write_helpers_produce_loadable_files(self, fresh_obs, tmp_path):
         reg, trc = fresh_obs
@@ -341,25 +417,24 @@ class TestInvariants:
             event="dispatches") > 0
         assert reg.counter("deepmap_plan_cache_events_total").items()
 
-    def test_dispatch_spans_overlap_collect_spans(self, fresh_obs, obs_store):
-        """The acceptance smoke test: on the hybrid store, the device
-        window (dispatch -> collect-start) of morsel i+1 must bracket
-        the host collect span of morsel i — the streaming executor
-        tops the dispatch window up BEFORE collecting, so the overlap
-        is structural, and the trace must show it."""
+    def test_next_dispatch_starts_before_collect_ends(self, fresh_obs, obs_store):
+        """The pipelining, from real spans: the streaming executor
+        dispatches morsel i+1 (its ``engine.dispatch`` span, opened
+        outside any collect) before the ``collect`` span of morsel i
+        ends, so the device computes i+1 while the host drains i."""
         _, store = obs_store
         store.query().morsel(256).scan().execute()
         _, trc = fresh_obs
-        dispatch = {s.args["morsel"]: s
-                    for s in trc.spans("infer_dispatch", track="device")}
-        collect = {s.args["morsel"]: s for s in trc.spans("collect", track="host")}
-        assert len(dispatch) >= 4  # multiple morsels actually streamed
-        overlaps = 0
-        for i, c in collect.items():
-            d_next = dispatch.get(i + 1)
-            if d_next is not None and d_next.start < c.start and d_next.end >= c.end:
-                overlaps += 1
-        assert overlaps >= len(collect) - 1 - 1  # all but the final morsel
+        # one chunk per morsel: the i-th top-level dispatch is morsel i's
+        dispatch = sorted((s for s in trc.spans("engine.dispatch") if not s.parent),
+                          key=lambda s: s.start)
+        collect = {s.args["morsel"]: s for s in trc.spans("collect")}
+        assert len(dispatch) == len(collect) >= 4  # multiple morsels streamed
+        for i in range(len(collect) - 1):
+            assert dispatch[i + 1].start < collect[i].end
+        waits = trc.spans("engine.wait")
+        assert len(waits) == len(collect)
+        assert all(w.parent == "collect" for w in waits)
 
     def test_chrome_trace_of_real_plan_is_perfetto_shaped(
         self, fresh_obs, obs_store
@@ -369,7 +444,7 @@ class TestInvariants:
         doc = json.loads(json.dumps(obs.to_chrome_trace()))
         xs = [e for e in doc["traceEvents"] if e["ph"] == "X"]
         cats = {e["cat"] for e in xs}
-        assert {"device", "host", "plans"} <= cats
+        assert cats == {"host", "plans"}
         for e in xs:
             assert e["ts"] >= 0 and e["dur"] >= 0
             assert isinstance(e["pid"], int) and isinstance(e["tid"], int)
