@@ -557,10 +557,12 @@ class ExplainStats:
     #: Probe keys scattered into the right store's existence index by
     #: a ``join(...)`` plan (summed across morsels).
     join_probes: int = 0
-    #: Keys probed in ``T_aux`` and partitions visited for them, as
+    #: Keys probed in ``T_aux``, partitions visited for them, and the
+    #: keys of those answered from the pool-resident sorted view, as
     #: ``AuxTable.get`` counts them.
     aux_keys: int = 0
     aux_visits: int = 0
+    aux_resident_keys: int = 0
     route_s: float = 0.0
     infer_s: float = 0.0
     #: The parts of ``infer_s`` spent in the ``engine.dispatch`` spans
@@ -601,6 +603,7 @@ class ExplainStats:
         self.join_probes += other.join_probes
         self.aux_keys += other.aux_keys
         self.aux_visits += other.aux_visits
+        self.aux_resident_keys += other.aux_resident_keys
         # one group seen by N morsels is still one group — keep the max
         self.groups_emitted = max(self.groups_emitted, other.groups_emitted)
         self.owners_failed = _union(self.owners_failed, other.owners_failed)

@@ -1,17 +1,34 @@
 """Auxiliary accuracy-assurance table ``T_aux`` (paper §IV-B1).
 
 Misclassified key-value pairs are sorted by key, range-partitioned, and
-each partition is compressed (Z-Standard or LZMA).  Lookup locates the
-partition by binary search over partition-boundary keys, decompresses it
-through the shared LRU :class:`~repro.storage.pool.MemoryPool`, and
-binary-searches inside.  We NEVER re-key (paper's emphasis) — original
-key order is preserved.
+each partition is compressed (Z-Standard or LZMA).  We NEVER re-key
+(paper's emphasis) — original key order is preserved.
+
+A probe takes one of two paths, picked per call from what the shared
+LRU :class:`~repro.storage.pool.MemoryPool` holds:
+
+* **resident** — when the whole table, decompressed, fits in the pool
+  without evicting another entry (this table's own partition entries
+  may be released for it), every partition is decompressed once into
+  one contiguous sorted view, ``keys (N,) int64`` and ``codes (N, m)
+  int32``, held in the pool as one entry charged its real bytes.  The
+  probe is then one ``searchsorted`` of the ordered queries over the
+  view: no Python loop per key or per partition.
+* **partitioned** — otherwise (a table over the pool, or a pool crowded
+  by other tables), each probed key's partition is located by binary
+  search over partition-boundary keys, decompressed through the pool
+  (paper §IV-B2: LRU partitions are evicted when memory is
+  insufficient), and binary-searched inside, once per partition a batch
+  hits.
+
+Both give the same answers and count partition visits alike.
 
 Modifications (Algorithms 3–5) land in a sorted in-memory delta overlay
 (inserts/updates) and a tombstone set (deletes of rows that live in
-compacted partitions); ``compact()`` folds both back into partitions.
-The delta is charged to Eq. 1 at its *compressed serialized* size, i.e.
-exactly what a flush would cost on disk.
+compacted partitions); ``compact()`` folds both back into partitions
+and drops the old resident view.  The delta is charged to Eq. 1 at its
+*compressed serialized* size, i.e. exactly what a flush would cost on
+disk.
 """
 
 from __future__ import annotations
@@ -38,6 +55,18 @@ def _unpack_partition(blob: bytes) -> Tuple[np.ndarray, np.ndarray]:
     keys = np.frombuffer(blob[16 : 16 + 8 * n], dtype=np.int64)
     codes = np.frombuffer(blob[16 + 8 * n :], dtype=np.int32).reshape(n, m)
     return keys, codes
+
+
+def _answer(pkeys, pcodes, qk, idx, tomb, found, out) -> None:
+    """Binary-search the sorted ``qk`` in the sorted ``pkeys``; mark each
+    hit that no tombstone hides as found at ``idx`` and copy its codes."""
+    pos = np.searchsorted(pkeys, qk)
+    hit = pkeys[np.minimum(pos, pkeys.shape[0] - 1)] == qk
+    if tomb is not None:
+        hit &= ~np.isin(qk, tomb)
+    sel = idx[hit]
+    found[sel] = True
+    out[sel] = pcodes[pos[hit]]
 
 
 class AuxTable:
@@ -98,6 +127,7 @@ class AuxTable:
             bounds.append(int(k[0]))
         self._boundaries = np.asarray(bounds, dtype=np.int64)
         self._compacted_rows = int(keys.shape[0])
+        self.pool.invalidate(self._view_key())
         self._generation += 1
 
     # -- partition access ------------------------------------------------------
@@ -110,20 +140,61 @@ class AuxTable:
 
         return self.pool.get(("aux", id(self), self._generation, idx), loader)
 
+    def _decompress_all(self) -> Tuple[np.ndarray, np.ndarray]:
+        """Every partition decompressed once, in key order, into one
+        contiguous ``(keys, codes)`` pair."""
+        keys = np.empty(self._compacted_rows, dtype=np.int64)
+        codes = np.empty((self._compacted_rows, self.num_values), dtype=np.int32)
+        with obs.span("aux.decompress"):
+            at = 0
+            for blob, rows in zip(self._partitions, self._part_rows):
+                k, c = _unpack_partition(self._codec.decompress(blob))
+                keys[at : at + rows] = k
+                codes[at : at + rows] = c
+                at += rows
+        return keys, codes
+
+    def _view_key(self) -> tuple:
+        return ("aux-flat", id(self), self._generation)
+
+    def _resident_view(self) -> Optional[Tuple[np.ndarray, np.ndarray]]:
+        """The resident sorted view, built on first use where it fits the
+        pool without evicting another entry; None where it does not."""
+        key = self._view_key()
+        view = self.pool.peek(key)
+        if view is None:
+            me = id(self)
+            view = self.pool.admit(
+                key,
+                self._compacted_rows * (8 + 4 * self.num_values),
+                self._decompress_all,
+                lambda k: k[0] == "aux" and k[1] == me,
+            )
+        return view
+
     # -- batched lookup ----------------------------------------------------------
     def get(self, keys: np.ndarray, stats=None) -> Tuple[np.ndarray, np.ndarray]:
         """Batched aux lookup.
 
         Returns ``(found_mask (n,) bool, codes (n, m) int32)``; rows not
-        present in T_aux have arbitrary codes and found=False.  Queries
-        are grouped per partition so each partition is decompressed at
-        most once per batch (paper §IV-B2).
+        present in T_aux have arbitrary codes and found=False.  The delta
+        overlay answers first, tombstones hide rows after; the rest are
+        ordered once by key and searched on the resident path (one
+        ``searchsorted`` over the pool-resident sorted view) where the
+        view is or can be resident, else on the partitioned path (each
+        partition hit decompressed at most once per batch, paper
+        §IV-B2).
 
         Timed by the ``aux.get`` span, whose args carry the keys probed
-        and the partitions visited.  Those counts are taken once per
-        call: into ``deepmap_aux_keys_total{outcome}`` (found or absent)
-        and ``deepmap_aux_partition_visits_total``, and into ``stats``
-        (an :class:`~repro.api.plan.ExplainStats`) when one is given.
+        (``keys``), the partitions their key ranges fall in (``visits``,
+        counted alike on both paths) and the keys answered on the
+        resident path (``resident``: all of them or none).  Those counts
+        are taken once per call: into ``deepmap_aux_keys_total{outcome}``
+        (found or absent), ``deepmap_aux_path_keys_total{path}``
+        (resident or partitioned) and
+        ``deepmap_aux_partition_visits_total``, and into ``stats`` (an
+        :class:`~repro.api.plan.ExplainStats`: ``aux_keys``,
+        ``aux_visits``, ``aux_resident_keys``) when one is given.
         """
         keys = np.asarray(keys, dtype=np.int64)
         n = keys.shape[0]
@@ -132,8 +203,8 @@ class AuxTable:
         if n == 0:
             return found, out
         with obs.span("aux.get") as span:
-            visits = self._probe(keys, found, out)
-        span.args.update(keys=n, visits=visits)
+            visits, resident = self._probe(keys, found, out)
+        span.args.update(keys=n, visits=visits, resident=resident)
         hits = int(np.count_nonzero(found))
         reg = obs.registry()
         probed = reg.counter(
@@ -141,6 +212,13 @@ class AuxTable:
         )
         probed.inc(hits, outcome="found")
         probed.inc(n - hits, outcome="absent")
+        by_path = reg.counter(
+            "deepmap_aux_path_keys_total",
+            "Keys probed in T_aux, by path: the resident sorted view or "
+            "the partitions.",
+        )
+        by_path.inc(resident, path="resident")
+        by_path.inc(n - resident, path="partitioned")
         reg.counter(
             "deepmap_aux_partition_visits_total",
             "T_aux partitions visited by lookups (one per partition a "
@@ -149,12 +227,16 @@ class AuxTable:
         if stats is not None:
             stats.aux_keys += n
             stats.aux_visits += visits
+            stats.aux_resident_keys += resident
         return found, out
 
-    def _probe(self, keys: np.ndarray, found: np.ndarray, out: np.ndarray) -> int:
+    def _probe(
+        self, keys: np.ndarray, found: np.ndarray, out: np.ndarray
+    ) -> Tuple[int, int]:
         """Fill ``found``/``out`` for ``keys``; returns the partitions
-        visited."""
-        visits = 0
+        visited and the keys answered on the resident path."""
+        view = self._resident_view() if self._partitions else None
+        resident = keys.shape[0] if view is not None else 0
 
         # Overlay first: delta wins over partitions; tombstones kill rows.
         if self._delta:
@@ -163,34 +245,34 @@ class AuxTable:
                 if row is not None:
                     found[i] = True
                     out[i] = row
-        tomb = self._tombstones
-
         remaining = np.flatnonzero(~found)
-        if remaining.size and self._partitions:
-            rkeys = keys[remaining]
-            pid = np.searchsorted(self._boundaries, rkeys, side="right") - 1
-            valid = pid >= 0
-            order = np.argsort(pid[valid], kind="stable")
-            ridx = remaining[valid][order]
-            rpid = pid[valid][order]
-            start = 0
-            while start < ridx.size:
-                end = start
-                p = rpid[start]
-                while end < ridx.size and rpid[end] == p:
-                    end += 1
-                visits += 1
-                pkeys, pcodes = self._load_partition(int(p))
-                qk = keys[ridx[start:end]]
-                pos = np.searchsorted(pkeys, qk)
-                hit = (pos < pkeys.shape[0]) & (pkeys[np.minimum(pos, pkeys.shape[0] - 1)] == qk)
-                if tomb:
-                    hit &= ~np.isin(qk, np.fromiter(tomb, dtype=np.int64, count=len(tomb)))
-                sel = ridx[start:end][hit]
-                found[sel] = True
-                out[sel] = pcodes[pos[hit]]
-                start = end
-        return visits
+        if not remaining.size or not self._partitions:
+            return 0, resident
+        tomb = (
+            np.fromiter(self._tombstones, dtype=np.int64, count=len(self._tombstones))
+            if self._tombstones
+            else None
+        )
+
+        # Order the queries once (scan morsels and server batches arrive
+        # sorted); each key's partition id is then non-decreasing.
+        rkeys = keys[remaining]
+        if not (rkeys[1:] >= rkeys[:-1]).all():
+            order = np.argsort(rkeys, kind="stable")
+            remaining, rkeys = remaining[order], rkeys[order]
+        pid = np.searchsorted(self._boundaries, rkeys, side="right") - 1
+        first = int(np.searchsorted(pid, 0))  # keys below every partition
+        cuts = np.flatnonzero(np.diff(pid[first:])) + (first + 1)
+        starts = np.concatenate(([first], cuts)) if first < pid.size else cuts
+
+        if view is not None:
+            _answer(*view, rkeys, remaining, tomb, found, out)
+        else:
+            ends = np.append(starts[1:], pid.size)
+            for s, e in zip(starts.tolist(), ends.tolist()):
+                _answer(*self._load_partition(int(pid[s])), rkeys[s:e],
+                        remaining[s:e], tomb, found, out)
+        return int(starts.size), resident
 
     def contains(self, keys: np.ndarray) -> np.ndarray:
         return self.get(keys)[0]
@@ -216,18 +298,9 @@ class AuxTable:
         self.add(keys, codes)
 
     def compact(self) -> None:
-        """Fold delta + tombstones into fresh sorted compressed partitions."""
-        all_keys, all_codes = [], []
-        for idx in range(len(self._partitions)):
-            k, c = self._load_partition(idx)
-            all_keys.append(k)
-            all_codes.append(c)
-        keys = np.concatenate(all_keys) if all_keys else _EMPTY_I64
-        codes = (
-            np.concatenate(all_codes)
-            if all_codes
-            else np.zeros((0, self.num_values), dtype=np.int32)
-        )
+        """Fold delta + tombstones into fresh sorted compressed partitions;
+        the old generation's resident view leaves the pool."""
+        keys, codes = self._decompress_all()
         if self._tombstones or self._delta:
             drop = np.fromiter(
                 set(self._tombstones) | set(self._delta), dtype=np.int64

@@ -469,7 +469,7 @@ class DeepMappingStore(MappingStore):
             # carry codes for ALL tasks; project to the evaluated ones.
             exist_idx = np.flatnonzero(exists)
             found, aux_codes = self.aux.get(ticket.keys[exist_idx], stats)
-            pred[exist_idx[found]] = aux_codes[found][:, task_idx]
+            pred[exist_idx[found]] = aux_codes[:, task_idx][found]
             stats.aux_s += time.perf_counter() - t3
             # Predicate filter on aux-corrected argmax codes: one
             # boolean gather per predicate, BEFORE any decode.
@@ -569,7 +569,7 @@ class DeepMappingStore(MappingStore):
             exist_idx = np.flatnonzero(exists)
             found, aux_codes = self.aux.get(ticket.keys[exist_idx], stats)
             task_idx = [self.spec.tasks.index(t) for t in pending.wanted]
-            codes[exist_idx[found]] = aux_codes[found][:, task_idx]
+            codes[exist_idx[found]] = aux_codes[:, task_idx][found]
             stats.aux_s += time.perf_counter() - t3
             match = None
             if preds:

@@ -38,12 +38,12 @@ class ServeStats:
     The full pipeline is covered: merge (concatenate + dedup, the
     ``serve.merge`` span), route (key-source/plan compile),
     infer/exist/aux/decode from the store hooks — ``dispatch_s`` and
-    ``wait_s`` are the engine's spans inside ``infer_s``, ``aux_keys``
-    and ``aux_visits`` the ``T_aux`` probe counts — filter (zero unless
-    a predicate plan is served), gather (the ``serve.scatter`` span
-    back to requesters).  Requests, keys and latencies are also
-    mirrored into the process metrics registry under
-    ``deepmap_serve_*`` for export."""
+    ``wait_s`` are the engine's spans inside ``infer_s``, ``aux_keys``,
+    ``aux_visits`` and ``aux_resident_keys`` the ``T_aux`` probe
+    counts — filter (zero unless a predicate plan is served), gather
+    (the ``serve.scatter`` span back to requesters).  Requests, keys
+    and latencies are also mirrored into the process metrics registry
+    under ``deepmap_serve_*`` for export."""
 
     requests: int = 0
     keys: int = 0
@@ -61,6 +61,7 @@ class ServeStats:
     gather_s: float = 0.0
     aux_keys: int = 0
     aux_visits: int = 0
+    aux_resident_keys: int = 0
     cache_hits: int = 0
     cache_misses: int = 0
     cache_bypass: int = 0
@@ -162,6 +163,7 @@ class LookupServer:
             self.stats.aux_s += morsel.stats.aux_s
             self.stats.aux_keys += morsel.stats.aux_keys
             self.stats.aux_visits += morsel.stats.aux_visits
+            self.stats.aux_resident_keys += morsel.stats.aux_resident_keys
             self.stats.filter_s += morsel.stats.filter_s
             self.stats.decode_s += morsel.stats.decode_s
         self.stats.route_s += run.route_s

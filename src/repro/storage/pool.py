@@ -63,6 +63,53 @@ class MemoryPool:
             self._used += nbytes
             return obj
 
+    def peek(self, key: Hashable):
+        """The cached object under ``key`` (a hit, now most recently
+        used), or None; never loads and counts no miss."""
+        with self._lock:
+            entry = self._entries.get(key)
+            if entry is None:
+                return None
+            self._entries.move_to_end(key)
+            self.hits += 1
+            return entry[0]
+
+    def admit(
+        self,
+        key: Hashable,
+        nbytes: int,
+        build: Callable[[], object],
+        releasable: Callable[[Hashable], bool],
+    ):
+        """Cache ``build()`` under ``key`` only where its ``nbytes`` fit
+        the budget without evicting any entry but those ``releasable``
+        marks, which are dropped to make room.  Returns the cached
+        object, or None where it does not fit (checked before building,
+        and again after, since ``build`` runs outside the lock; a build
+        counts as one miss)."""
+        with self._lock:
+            if not self._room_for(nbytes, releasable):
+                return None
+            self.misses += 1
+        obj = build()
+        with self._lock:
+            entry = self._entries.get(key)
+            if entry is not None:  # another thread admitted it meanwhile
+                return entry[0]
+            if not self._room_for(nbytes, releasable):
+                return None
+            for k in [k for k in self._entries if releasable(k)]:
+                self._used -= self._entries.pop(k)[1]
+            self._entries[key] = (obj, nbytes)
+            self._used += nbytes
+            return obj
+
+    def _room_for(self, nbytes: int, releasable: Callable[[Hashable], bool]) -> bool:
+        if nbytes > self.budget_bytes:
+            return False
+        freed = sum(n for k, (_, n) in self._entries.items() if releasable(k))
+        return self._used - freed + nbytes <= self.budget_bytes
+
     def invalidate(self, key: Hashable) -> None:
         with self._lock:
             entry = self._entries.pop(key, None)

@@ -1,6 +1,7 @@
 import numpy as np
 import pytest
 
+from repro.api.plan import ExplainStats
 from repro.core.aux_table import AuxTable
 from repro.storage import MemoryPool
 
@@ -165,3 +166,130 @@ class TestAuxCounters:
         (miss,) = trc.spans("aux.decompress")
         assert miss.parent == "aux.get"
         assert (aux.pool.hits, aux.pool.misses) == (1, 1)
+
+
+#: pool budgets: the whole table fits (resident view) / it does not
+POOLS = {"resident": 1 << 30, "partitioned": 4096}
+
+
+def _reference_probe(keys, codes, aux, delta, dead, q):
+    """A per-key loop over a dict: the expected found mask, the codes of
+    the found rows, and the partitions visited by keys the delta misses."""
+    lut = {int(k): c for k, c in zip(keys, codes)}
+    lut.update(delta)
+    for k in dead:
+        lut.pop(k, None)
+    found = np.array([int(k) in lut for k in q])
+    got = np.array([lut[int(k)] for k in q if int(k) in lut]).reshape(-1, codes.shape[1])
+    bounds = keys[:: aux._part_rows[0]]
+    visits = {int(np.searchsorted(bounds, k, "right")) - 1 for k in q if int(k) not in delta}
+    visits.discard(-1)
+    return found, got, len(visits)
+
+
+class TestProbePaths:
+    """The resident sorted view and the partitioned path give the same
+    answers and counts; the view is built only where it fits the pool."""
+
+    @pytest.mark.parametrize("path", sorted(POOLS))
+    def test_paths_answer_and_count_alike(self, path):
+        keys, codes, aux = make_aux(n=600, pool=MemoryPool(POOLS[path]))
+        assert aux._compacted_rows * (8 + 4 * 3) > POOLS["partitioned"]
+        rng = np.random.default_rng(7)
+        delta = {10**6: np.array([1, 2, 3], np.int32), int(keys[9]): np.array([9, 9, 9], np.int32)}
+        aux.add(np.array(list(delta), np.int64), np.stack(list(delta.values())))
+        dead = [int(k) for k in keys[40:46]]
+        aux.remove(np.array(dead, np.int64))
+        inside_absent = np.setdiff1d(np.arange(keys[0], keys[-1]), keys)[::37]
+        q = rng.permutation(np.concatenate([
+            keys, keys[::5], keys[::11],                  # duplicates
+            inside_absent,                                # between stored keys
+            [keys[0] - 1, -7, keys[-1] + 1, 10**7],       # below / above every boundary
+            list(delta),                                  # delta overlay
+        ]).astype(np.int64))
+        want_found, want_codes, want_visits = _reference_probe(keys, codes, aux, delta, dead, q)
+        stats = ExplainStats()
+        found, got = aux.get(q, stats)
+        np.testing.assert_array_equal(found, want_found)
+        np.testing.assert_array_equal(got[found], want_codes)
+        assert (stats.aux_keys, stats.aux_visits) == (q.size, want_visits)
+        assert stats.aux_resident_keys == (q.size if path == "resident" else 0)
+        view_key = ("aux-flat", id(aux), aux._generation)
+        assert (aux.pool.peek(view_key) is not None) == (path == "resident")
+
+    @pytest.mark.parametrize("path", sorted(POOLS))
+    def test_hand_built_table_counts_on_both_paths(self, path):
+        from repro import obs
+
+        keys = np.arange(0, 200, 10, dtype=np.int64)
+        codes = np.arange(40, dtype=np.int32).reshape(20, 2)
+        # the whole table is 20 rows of 16 bytes: 320 bytes decompressed
+        pool = MemoryPool(POOLS[path] if path == "resident" else 200)
+        aux = AuxTable.build(keys, codes, codec="none", partition_bytes=100, pool=pool)
+        probe = np.array([0, 40, 100, 155, -5, 10], dtype=np.int64)
+        stats = ExplainStats()
+        trc, reg = obs.Tracer(), obs.MetricsRegistry()
+        prev_t, prev_r = obs.set_tracer(trc), obs.set_registry(reg)
+        try:
+            found, got = aux.get(probe, stats)
+        finally:
+            obs.set_tracer(prev_t)
+            obs.set_registry(prev_r)
+        assert found.tolist() == [True, True, True, False, False, True]
+        np.testing.assert_array_equal(got[found], codes[[0, 4, 10, 1]])
+        resident = probe.size if path == "resident" else 0
+        assert (stats.aux_keys, stats.aux_visits, stats.aux_resident_keys) == (6, 3, resident)
+        (span,) = trc.spans("aux.get")
+        assert span.args == {"keys": 6, "visits": 3, "resident": resident}
+        by_path = reg.counter("deepmap_aux_path_keys_total")
+        assert by_path.value(path="resident") == resident
+        assert by_path.value(path="partitioned") == probe.size - resident
+
+    def test_view_not_built_where_it_would_evict_another_table(self):
+        pool = MemoryPool(15_000)
+        keys_a, codes_a, a = make_aux(n=500, pool=pool, seed=1)
+        keys_b, codes_b, b = make_aux(n=500, pool=pool, seed=2)
+        a.get(keys_a)
+        view_a = ("aux-flat", id(a), a._generation)
+        assert pool.used_bytes == 500 * 20 and pool.peek(view_a) is not None
+        found, got = b.get(keys_b[:60])                   # two of b's partitions
+        assert found.all()
+        np.testing.assert_array_equal(got, codes_b[:60])
+        assert pool.peek(("aux-flat", id(b), b._generation)) is None
+        assert pool.peek(view_a) is not None and pool.evictions == 0
+        stats = ExplainStats()
+        b.get(keys_b[:60], stats)
+        assert stats.aux_resident_keys == 0 and stats.aux_visits == 2
+
+    def test_view_releases_only_its_own_partitions(self):
+        pool = MemoryPool(100)
+        pool.get(("aux", 1, 1, 0), lambda: ("own", 30))
+        pool.get(("aux", 2, 1, 0), lambda: ("other", 30))
+        built = []
+
+        def build():
+            built.append(True)
+            return "view"
+
+        mine = lambda k: k[0] == "aux" and k[1] == 1  # noqa: E731
+        assert pool.admit(("aux-flat", 1, 1), 71, build, mine) is None
+        assert not built and pool.used_bytes == 60
+        assert pool.admit(("aux-flat", 1, 1), 70, build, mine) == "view"
+        assert pool.used_bytes == 100 and pool.evictions == 0
+        assert pool.peek(("aux", 1, 1, 0)) is None and pool.peek(("aux", 2, 1, 0)) == "other"
+        assert (pool.misses, pool.hits) == (3, 1)
+
+    def test_compact_drops_the_old_view(self):
+        keys, codes, aux = make_aux()
+        aux.get(keys[:10])
+        old = ("aux-flat", id(aux), aux._generation)
+        assert aux.pool.peek(old) is not None
+        aux.remove(keys[:1])
+        aux.compact()
+        assert aux.pool.peek(old) is None and aux.pool.used_bytes == 0
+        stats = ExplainStats()
+        found, got = aux.get(keys, stats)
+        assert found.tolist() == [False] + [True] * (keys.size - 1)
+        np.testing.assert_array_equal(got[1:], codes[1:])
+        assert stats.aux_resident_keys == keys.size
+        assert aux.pool.used_bytes == (keys.size - 1) * (8 + 4 * 3)
