@@ -213,16 +213,18 @@ def _describe_failure(exc: BaseException) -> Tuple[dict, ...]:
     ).describe(),)
 
 
-def _resolve_keys(store, plan: QueryPlan) -> Tuple[np.ndarray, float]:
-    """KeySource operator: materialize the plan's key stream."""
-    t0 = time.perf_counter()
+def _resolve_keys(store, plan: QueryPlan) -> Tuple[np.ndarray, int]:
+    """KeySource operator: materialize the plan's key stream.  Returns
+    the keys and the key slots the existence index walked for them:
+    ``hi - lo`` for a range, every slot up to the last key for a scan,
+    none for explicit keys."""
     if plan.kind == "point":
-        keys = np.asarray(plan.keys, dtype=np.int64)
-    elif plan.kind == "range":
-        keys = store._range_keys(int(plan.lo), int(plan.hi))
-    else:  # scan
-        keys = store._all_keys()
-    return keys, time.perf_counter() - t0
+        return np.asarray(plan.keys, dtype=np.int64), 0
+    if plan.kind == "range":
+        lo, hi = int(plan.lo), int(plan.hi)
+        return store._range_keys(lo, hi), max(0, hi - max(0, lo))
+    keys = store._all_keys()
+    return keys, int(keys[-1]) + 1 if keys.size else 0
 
 
 class PlanStream:
@@ -250,18 +252,6 @@ class PlanStream:
         self._t_plan0 = time.perf_counter()
         self.fixed = plan.morsel is not None
         self._morsel_rows = plan.morsel_rows()
-        if not self.fixed:
-            # Cost-model seed (satellite of the device-residency work):
-            # start adaptive sizing from the store's model footprint
-            # instead of a fixed 2^16.  Baselines (no "model" component)
-            # keep the DEFAULT_MORSEL seed bit-for-bit.
-            self._morsel_rows = seed_morsel_rows(
-                int(store.size_breakdown().get("model", 0)),
-                max_rows=getattr(
-                    getattr(store, "config", None), "inference_batch",
-                    ADAPT_MAX,
-                ),
-            )
         self.fanout = True if plan.fanout is None else plan.fanout
         self.preds: Tuple[Predicate, ...] = (
             plan.predicates if plan.pushdown else ()
@@ -271,12 +261,6 @@ class PlanStream:
         #: partial state; ``pushdown=False`` keeps rows flowing and the
         #: gatherer aggregates post-hoc (the reference path).
         self.agg_below = bool(plan.aggregates) and plan.pushdown
-        #: Dispatch capability: the store will evaluate these pushdown
-        #: predicates in-kernel (match bits ride the inference call), so
-        #: the executor's host Filter stage is expected to be a no-op.
-        self.kernel_filter = bool(self.preds) and bool(
-            store.supports_kernel_filter(self.preds)
-        )
         #: range/scan keys come from the existence index, so every key
         #: is known to exist — the hint baseline partition pruning needs.
         self.keys_exist = plan.kind != "point"
@@ -291,17 +275,23 @@ class PlanStream:
         self.cache_state = "bypass" if fp is None else (
             "hit" if entry is not None else "miss"
         )
+        with obs.span(
+            "exec.key_source", kind=plan.kind, cache=self.cache_state
+        ) as span:
+            if entry is not None:
+                self.keys = (
+                    np.asarray(plan.keys, dtype=np.int64)
+                    if plan.kind == "point"
+                    else entry.keys
+                )
+                self.slots = 0
+            else:
+                self.keys, self.slots = _resolve_keys(store, plan)
+        span.args.update(rows=int(self.keys.shape[0]), slots=self.slots)
+        self.route_s = span.seconds
         if entry is not None:
-            t0 = time.perf_counter()
-            self.keys = (
-                np.asarray(plan.keys, dtype=np.int64)
-                if plan.kind == "point"
-                else entry.keys
-            )
             self.columns = entry.columns
-            self.route_s = time.perf_counter() - t0
         else:
-            self.keys, self.route_s = _resolve_keys(store, plan)
             # Post-hoc filtering evaluates on decoded values, so the
             # predicate columns must be decoded even when the projection
             # excludes them (_finalize_morsel drops them after filtering).
@@ -322,11 +312,25 @@ class PlanStream:
                 None if plan.kind == "point" else self.keys,
                 self.columns,
             )
-        now = time.perf_counter()
-        obs.tracer().add_span(
-            "key_source", now - self.route_s, now, track="host",
-            kind=plan.kind, cache=self.cache_state,
+        #: Dispatch capability: the store will evaluate these pushdown
+        #: predicates in-kernel for this plan's heads (projection and
+        #: predicate columns; match bits ride the inference call), so
+        #: the executor's host Filter stage is expected to be a no-op.
+        self.kernel_filter = bool(self.preds) and bool(
+            store.supports_kernel_filter(self.preds, self.columns)
         )
+        if not self.fixed:
+            # Cost-model seed: start adaptive sizing from the bytes of
+            # the model this plan evaluates (its projection and
+            # predicate heads), instead of a fixed 2^16.  Baselines (no
+            # model) keep the DEFAULT_MORSEL seed bit-for-bit.
+            self._morsel_rows = seed_morsel_rows(
+                store.model_bytes(columns_with_predicates(self.columns, self.preds)),
+                max_rows=getattr(
+                    getattr(store, "config", None), "inference_batch",
+                    ADAPT_MAX,
+                ),
+            )
         self.sizes: List[int] = []  # dispatched morsel sizes (evidence)
         self._cursor = 0
         self._dispatched = 0
@@ -522,6 +526,13 @@ class PlanStream:
         )
         if stats.infer_s > 0:
             stages.inc(stats.infer_s, stage="infer")
+        if stats.filter_host_rows:
+            reg.counter(
+                "deepmap_executor_filter_host_rows_total",
+                "Rows whose predicate match the host evaluated: kernel "
+                "match bits re-run on aux-corrected codes, or the host "
+                "filter.",
+            ).inc(stats.filter_host_rows, kind=kind)
         for op, field in _STAGE_FIELDS:
             d = getattr(stats, field)
             if d > 0:
@@ -548,6 +559,14 @@ class PlanStream:
             "deepmap_executor_stage_seconds_total",
             "Cumulative per-operator seconds, from store stage timings.",
         ).inc(self.route_s, stage="key_source")
+        reg.counter(
+            "deepmap_executor_key_source_rows_total",
+            "Keys the key source produced, by plan kind.",
+        ).inc(int(self.keys.shape[0]), kind=kind)
+        reg.counter(
+            "deepmap_executor_key_source_slots_total",
+            "Key slots the existence index walked for them, by plan kind.",
+        ).inc(self.slots, kind=kind)
 
 
 # --------------------------------------------------------------- finalize
@@ -1036,7 +1055,8 @@ def execute_plan_staged(store, plan: QueryPlan):
     here — the staged path IS a reference) and joins resolve as one
     synchronous probe."""
     t0 = time.perf_counter()
-    keys, route_s = _resolve_keys(store, plan)
+    keys, _ = _resolve_keys(store, plan)
+    route_s = time.perf_counter() - t0
     num_keys = int(keys.shape[0])
     selected = (
         aggregate_columns(plan.group_by, plan.aggregates)

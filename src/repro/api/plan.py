@@ -152,8 +152,9 @@ def evaluate_predicates(
     THE post-hoc evaluator (executor morsels, the staged reference
     path, and the stores' generic overlay-view fallback all call this
     one function, so conjunction semantics cannot drift).  Records
-    ``filter_s``/``predicates``/``rows_matched`` on ``stats`` and
-    returns the row selector (``exists`` AND every predicate)."""
+    ``filter_s``/``predicates``/``rows_matched``/``filter_host_rows``
+    on ``stats`` and returns the row selector (``exists`` AND every
+    predicate)."""
     t0 = time.perf_counter()
     match = exists.copy()
     for p in predicates:
@@ -161,6 +162,7 @@ def evaluate_predicates(
     stats.filter_s += time.perf_counter() - t0
     stats.predicates = tuple(p.describe() for p in predicates)
     stats.rows_matched += int(match.sum())
+    stats.filter_host_rows += int(np.count_nonzero(exists))
     return match
 
 
@@ -532,6 +534,10 @@ class ExplainStats:
     predicates: Tuple[str, ...] = ()
     rows_decoded: int = 0
     rows_matched: int = 0
+    #: Rows whose predicate match the host evaluated: on the in-kernel
+    #: path the aux-overridden rows whose match bits it re-ran on their
+    #: corrected codes, else every existing row it filtered.
+    filter_host_rows: int = 0
     #: True when the pushed-down predicates were evaluated *in-kernel*
     #: (fused Pallas tier emitted match bits with the codes), so the
     #: host filter stage only patched aux-overridden rows.  ``filter_s``
@@ -597,6 +603,7 @@ class ExplainStats:
         self.gather_s += other.gather_s
         self.rows_decoded += other.rows_decoded
         self.rows_matched += other.rows_matched
+        self.filter_host_rows += other.filter_host_rows
         self.partitions_pruned += other.partitions_pruned
         self.retries += other.retries
         self.keys_unresolved += other.keys_unresolved

@@ -267,10 +267,20 @@ class MappingStore(abc.ABC):
         stats.agg_s += time.perf_counter() - t0
         return state, stats
 
-    def supports_kernel_filter(self, predicates: tuple = ()) -> bool:
+    def model_bytes(self, columns: Optional[Tuple[str, ...]] = None) -> int:
+        """Bytes of the model weights a plan reading ``columns`` (None:
+        every column) evaluates, 0 for a store without a model; the
+        executor seeds a plan's adaptive morsels from it.  The default
+        is the whole model of :meth:`size_breakdown`."""
+        return int(self.size_breakdown().get("model", 0))
+
+    def supports_kernel_filter(
+        self, predicates: tuple = (), columns: Optional[Tuple[str, ...]] = None
+    ) -> bool:
         """Dispatch capability flag: ``True`` when the pushed-down
-        ``predicates`` would be evaluated *inside* the store's device
-        kernel (match bits emitted alongside codes + exist bits), so
+        ``predicates`` of a plan projecting ``columns`` (None: every
+        column) would be evaluated *inside* the store's device kernel
+        (match bits emitted alongside codes + exist bits), so
         the executor's host ``Filter`` stage is redundant and may be
         skipped.  The default is ``False`` — baseline stores filter on
         the host.  Advisory only: the executor still honours the

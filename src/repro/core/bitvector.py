@@ -26,7 +26,7 @@ _POPCOUNT8 = np.unpackbits(np.arange(256, dtype=np.uint8)[:, None], axis=1).sum(
 class BitVector:
     """Dynamic packed bitvector over a non-negative integer key domain."""
 
-    __slots__ = ("_words", "_capacity", "_version")
+    __slots__ = ("_words", "_capacity", "_version", "_sized")
 
     def __init__(self, capacity: int):
         if capacity < 0:
@@ -34,6 +34,7 @@ class BitVector:
         self._capacity = int(capacity)
         self._words = np.zeros((self._capacity + 63) // 64, dtype=np.uint64)
         self._version = 0
+        self._sized = None  # (version, at-rest bytes) of the last size_bytes()
 
     # -- construction ------------------------------------------------------
     @classmethod
@@ -135,5 +136,11 @@ class BitVector:
         return bv
 
     def size_bytes(self) -> int:
-        """At-rest (compressed) size — the Eq. 1 contribution."""
-        return len(self.to_bytes())
+        """At-rest (compressed) size — the Eq. 1 contribution.  Compressed
+        once per mutation version: a composite store's adaptive plans
+        read the size breakdown to seed their morsels
+        (``MappingStore.model_bytes``), and compressing a 48M-slot index
+        takes tens of milliseconds."""
+        if self._sized is None or self._sized[0] != self._version:
+            self._sized = (self._version, len(self.to_bytes()))
+        return self._sized[1]

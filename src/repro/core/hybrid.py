@@ -341,17 +341,25 @@ class DeepMappingStore(MappingStore):
         ))
         pending.next_start = min(start + bs, pending.keys.shape[0])
 
-    def supports_kernel_filter(self, predicates: tuple = ()) -> bool:
-        """True when ``predicates`` would be evaluated in-kernel: every
-        predicate column is a model head and the full wanted head set
-        fits the resident ``fused`` tier (the streamed and jit tiers
-        filter on the host).  Checked per plan by the executor to skip
-        its host ``Filter`` stage."""
+    def supports_kernel_filter(
+        self, predicates: tuple = (), columns: Optional[Tuple[str, ...]] = None
+    ) -> bool:
+        """True when ``predicates`` would be evaluated in-kernel for a
+        plan projecting ``columns`` (None: every column): every
+        predicate column is a model head and the plan's own heads (the
+        projection and the predicate columns, the set
+        :meth:`_dispatch_lookup` evaluates) fit the resident ``fused``
+        tier (the streamed and jit tiers filter on the host).  Checked
+        per plan by the executor to skip its host ``Filter`` stage."""
         if not self.config.use_pallas or not predicates:
             return False
         if any(p.column not in self.spec.tasks for p in predicates):
             return False
-        return self.engine.kernel_filter_capable(self.spec.tasks)
+        pred_cols = {p.column for p in predicates}
+        return self.engine.kernel_filter_capable(tuple(
+            t for t in self.spec.tasks
+            if t in pred_cols or columns is None or t in columns
+        ))
 
     def _dispatch_precomputed(
         self,
@@ -471,32 +479,11 @@ class DeepMappingStore(MappingStore):
             found, aux_codes = self.aux.get(ticket.keys[exist_idx], stats)
             pred[exist_idx[found]] = aux_codes[:, task_idx][found]
             stats.aux_s += time.perf_counter() - t3
-            # Predicate filter on aux-corrected argmax codes: one
-            # boolean gather per predicate, BEFORE any decode.
             if preds:
-                with obs.span("store.filter") as span:
-                    if ticket.match is not None:
-                        # In-kernel filter: the fused kernel already
-                        # ANDed the predicate code tables over the model
-                        # codes and exist bits; only the (few)
-                        # aux-overridden rows can have changed codes, so
-                        # re-evaluate just those on their corrected codes
-                        # via the full host tables.
-                        match = ticket.match
-                        aux_rows = exist_idx[found]
-                        if aux_rows.size:
-                            patched = np.ones(aux_rows.shape[0], dtype=bool)
-                            for wi, table, _ in preds:
-                                patched &= table[pred[aux_rows, wi]]
-                            match[aux_rows] = patched
-                    else:
-                        stats.kernel_filtered = False
-                        match = exists.copy()
-                        for wi, table, _ in preds:
-                            codes_w = np.where(exists, pred[:, wi], 0)
-                            match &= table[codes_w]
-                    hit = np.flatnonzero(match)
-                stats.filter_s += span.seconds
+                match = self._filter_chunk(
+                    ticket, pred, exists, exist_idx[found], preds, stats
+                )
+                hit = np.flatnonzero(match)
                 stats.rows_matched += int(hit.size)
                 # line 13: decode ONLY the matching rows.
                 with obs.span("store.decode") as span:
@@ -573,24 +560,40 @@ class DeepMappingStore(MappingStore):
             stats.aux_s += time.perf_counter() - t3
             match = None
             if preds:
-                with obs.span("store.filter") as span:
-                    if ticket.match is not None:
-                        match = ticket.match
-                        aux_rows = exist_idx[found]
-                        if aux_rows.size:
-                            patched = np.ones(aux_rows.shape[0], dtype=bool)
-                            for wi, table, _ in preds:
-                                patched &= table[codes[aux_rows, wi]]
-                            match[aux_rows] = patched
-                    else:
-                        stats.kernel_filtered = False
-                        match = exists.copy()
-                        for wi, table, _ in preds:
-                            codes_w = np.where(exists, codes[:, wi], 0)
-                            match &= table[codes_w]
-                stats.filter_s += span.seconds
+                match = self._filter_chunk(
+                    ticket, codes, exists, exist_idx[found], preds, stats
+                )
                 stats.rows_matched += int(match.sum())
             yield codes, exists, match
+
+    @staticmethod
+    def _filter_chunk(ticket, codes, exists, aux_rows, preds, stats) -> np.ndarray:
+        """Predicate filter on one chunk's aux-corrected argmax codes:
+        one boolean gather per predicate, BEFORE any decode.  Where the
+        fused kernel already ANDed the predicate code tables over the
+        model codes and exist bits (``ticket.match``), only the
+        aux-overridden rows ``aux_rows`` can have changed codes, so just
+        those are re-evaluated on their corrected codes via the full
+        host tables; otherwise the host filters every row.  Records
+        ``filter_s`` and ``filter_host_rows`` on ``stats``."""
+        with obs.span("store.filter") as span:
+            if ticket.match is not None:
+                match = ticket.match
+                if aux_rows.size:
+                    patched = np.ones(aux_rows.shape[0], dtype=bool)
+                    for wi, table, _ in preds:
+                        patched &= table[codes[aux_rows, wi]]
+                    match[aux_rows] = patched
+                host_rows = int(aux_rows.size)
+            else:
+                stats.kernel_filtered = False
+                match = exists.copy()
+                for wi, table, _ in preds:
+                    match &= table[np.where(exists, codes[:, wi], 0)]
+                host_rows = int(np.count_nonzero(exists))
+        stats.filter_s += span.seconds
+        stats.filter_host_rows += host_rows
+        return match
 
     def _collect_aggregate(self, pending: _PendingLookup, group_by, aggregates):
         """Code-space ``group_by(...).agg(...)``: consume aux-corrected
@@ -845,6 +848,16 @@ class DeepMappingStore(MappingStore):
             "decode_map": sum(c.size_bytes() for c in self.codecs.values())
             + self.encoder.size_bytes(),
         }
+
+    def model_bytes(self, columns: Optional[Tuple[str, ...]] = None) -> int:
+        """Bytes of the shared trunk and of the heads of ``columns``
+        (None: every head): what a plan reading them evaluates."""
+        heads = self.params["heads"]
+        return model_lib.model_size_bytes({
+            "shared": self.params["shared"],
+            "heads": {t: heads[t] for t in self.spec.tasks
+                      if columns is None or t in columns},
+        })
 
     def size_bytes(self) -> int:
         return sum(self.size_breakdown().values())

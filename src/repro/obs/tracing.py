@@ -56,6 +56,7 @@ DEFAULT_CAPACITY = 32768
 PROFILER_SPANS = (
     "serve.merge",      # LookupServer: concatenate requests .. np.unique
     "serve.scatter",    # LookupServer: per-column concat + gather to callers
+    "exec.key_source",  # executor: a plan's key stream (index walk or cache)
     "collect",          # executor: one morsel's host half
     "engine.dispatch",  # InferenceEngine.dispatch: featurize, upload, launch
     "engine.wait",      # InferenceEngine.collect: blocked on device outputs

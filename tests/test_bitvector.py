@@ -75,3 +75,18 @@ class TestCountAndVersion:
         assert bv.version > v1
         bv.set(np.array([], dtype=np.int64), True)  # no-op: unchanged
         assert bv.version > v1 and bv.version == v1 + 1
+
+    def test_size_bytes_compressed_once_per_version(self, monkeypatch):
+        """The at-rest size is the compressed length, recomputed only
+        after a mutation."""
+        bv = BitVector.from_keys(np.arange(0, 10_000, 3))
+        calls = []
+        to_bytes = BitVector.to_bytes
+        monkeypatch.setattr(BitVector, "to_bytes",
+                            lambda self: calls.append(1) or to_bytes(self))
+        first = bv.size_bytes()
+        assert bv.size_bytes() == first == len(to_bytes(bv)) and len(calls) == 1
+        bv.set(np.random.default_rng(0).integers(0, 10_000, 2_000), True)
+        assert bv.size_bytes() == len(to_bytes(bv)) != first and len(calls) == 2
+        restored = BitVector.from_bytes(to_bytes(bv))
+        assert restored.size_bytes() == bv.size_bytes()

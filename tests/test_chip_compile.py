@@ -117,6 +117,36 @@ def test_fused_lookup_with_exists_and_predicates(one_chip):
     assert "%fused_lookup.1 = " in text
 
 
+def test_fused_lookup_q12_selection(one_chip):
+    """TPC-H Q12's lineitem selection at SF1: the four heads it reads
+    (l_shipmode's 7 modes, three dates of up to 2,557 days) over the
+    existence words of a 48M-slot key domain (``orderkey * 8 +
+    linenumber``), with three predicate tables, two on one head."""
+    max_key = 6_000_000 * 8 + 7
+    spec = MLPSpec(
+        base=10, width=KeyEncoder(max_key).width, shared=SHARED,
+        private={t: PRIVATE for t in ("mode", "ship", "commit", "receipt")},
+        out_cards={"mode": 7, "ship": 2526, "commit": 2466, "receipt": 2557},
+    )
+    tasks = (spec.tasks.index("mode"),) + (spec.tasks.index("receipt"),) * 2
+    keys = jax.ShapeDtypeStruct((BATCH,), jnp.int32, sharding=one_chip)
+    words = jax.ShapeDtypeStruct((max_key // 32 + 1,), jnp.uint32, sharding=one_chip)
+    tables = tuple(
+        jax.ShapeDtypeStruct(
+            (kops._round_up(spec.card_map[spec.tasks[i]], kops.LANE),), jnp.int32,
+            sharding=one_chip,
+        )
+        for i in tasks
+    )
+    enc = KeyEncoder(max_key)
+    text = fm.fused_lookup_call.lower(
+        keys, words, _flat(spec, one_chip), spec, TILE, kops.LANE,
+        enc.position_ops(), enc.capacity, False,
+        pred_tables=tables, pred_tasks=tasks,
+    ).compile().as_text()
+    assert "tpu_custom_call" in text and "%fused_lookup.1 = " in text
+
+
 def test_fused_lookup_codes_only(one_chip):
     """The ``fused_streamed`` pages past the first."""
     text = _compile_lookup(_spec(), one_chip, with_words=False).as_text()

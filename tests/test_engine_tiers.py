@@ -243,6 +243,46 @@ class TestKernelPredicateFilter:
                 np.testing.assert_array_equal(rk.values[c], rh.values[c])
 
 
+def test_kernel_filter_judged_on_the_plan_heads(monkeypatch):
+    """A store with one head too wide for the resident fused tier: a
+    plan whose projection and predicate columns leave that head out is
+    promised the in-kernel filter and its label reads
+    ``filter[kernel:...]``; a plan over every head is not.  Both give
+    the table's answers."""
+    from repro.api.executor import PlanStream
+    from repro.core import Table
+
+    base = make_periodic_table(n=1200, period=16, cards=(5, 3))
+    table = Table(keys=base.keys, columns={
+        **base.columns, "wide": (np.arange(1200) % 1000).astype(np.int32)})
+    store = DeepMappingStore.build(table, DeepMappingConfig(
+        shared=(32,), private=(8,), train=TrainConfig(epochs=2, batch_size=512),
+        use_pallas=True,
+    ))
+    eng = store.engine
+    narrow, full = (
+        kops.resident_bytes(eng._entry(tasks).spec, eng.tile_n)
+        for tasks in (("col0", "col1"), store.spec.tasks)
+    )
+    monkeypatch.setenv("REPRO_VMEM_BUDGET", str((narrow + full) // 2))
+    store.attach_engine(InferenceEngine.for_store(store))
+    want = np.sort(table.keys[table.columns["col1"] == 1])
+    narrow_q = store.query().select("col0").scan().where("col1", "==", 1)
+    wide_q = store.query().scan().where("col1", "==", 1)
+    assert PlanStream(store, narrow_q.plan()).kernel_filter
+    assert not PlanStream(store, wide_q.plan()).kernel_filter
+    assert not store.supports_kernel_filter(narrow_q.plan().predicates)
+    rn, rw = narrow_q.execute(), wide_q.execute()
+    assert any(s.startswith("filter[kernel:") for s in rn.explain.plan)
+    assert not any(s.startswith("filter[kernel:") for s in rw.explain.plan)
+    np.testing.assert_array_equal(rn.keys, want)
+    np.testing.assert_array_equal(rw.keys, want)
+    np.testing.assert_array_equal(rn.values["col0"], rw.values["col0"])
+    np.testing.assert_array_equal(
+        rw.values["col0"], table.columns["col0"][np.searchsorted(table.keys, want)]
+    )
+
+
 class TestMorselSeed:
     """Pure seeding rule: pick the initial morsel from the model's
     weight bytes instead of always starting at ``DEFAULT_MORSEL``."""
@@ -272,6 +312,33 @@ class TestMorselSeed:
         assert seed_morsel_rows(1_000, max_rows=1 << 14) == 1 << 14
         # a cap below ADAPT_MIN clamps up, never under
         assert seed_morsel_rows(1_000, max_rows=16) == ADAPT_MIN
+
+    def test_seed_judged_on_the_plan_heads(self, tmp_path):
+        """A plan is seeded from the weights it evaluates, the trunk and
+        its projection and predicate heads, not the store's whole model:
+        a plan that leaves out a wide head starts on larger morsels."""
+        from repro.api.executor import PlanStream
+        from repro.core import Table
+
+        base = make_periodic_table(n=4000, period=16, cards=(5, 3))
+        table = Table(keys=base.keys, columns={
+            **base.columns, "wide": (np.arange(4000) % 3000).astype(np.int32)})
+        store = DeepMappingStore.build(table, DeepMappingConfig(
+            shared=(32,), private=(64,), train=TrainConfig(epochs=1, batch_size=512),
+        ))
+        whole = store.model_bytes()
+        assert whole == store.size_breakdown()["model"]
+        reopened_dir = str(tmp_path / "s")
+        store.save(reopened_dir)
+        assert DeepMappingStore.load(reopened_dir).model_bytes() == whole
+        narrow = store.model_bytes(("col0", "col1"))
+        assert narrow < store.model_bytes(("col0", "wide")) < whole
+        cap = store.config.inference_batch
+        plan = store.query().select("col0").scan().where("col1", "==", 1).plan()
+        assert PlanStream(store, plan)._morsel_rows == seed_morsel_rows(narrow, cap)
+        wide = store.query().scan().where("col1", "==", 1).plan()
+        assert PlanStream(store, wide)._morsel_rows == seed_morsel_rows(whole, cap)
+        assert seed_morsel_rows(narrow, cap) > seed_morsel_rows(whole, cap)
 
     def test_monotone_in_model_size(self):
         sizes = [1 << s for s in range(10, 31, 2)]

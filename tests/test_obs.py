@@ -460,3 +460,62 @@ class TestInvariants:
         assert len(trc) == 0
         assert reg.counter("deepmap_executor_morsels_total").value(
             kind="point") == 0
+
+
+class TestKeySourceAndHostFilter:
+    """The key source's ``exec.key_source`` span and the host filter's
+    ``filter_host_rows`` count, with their Prometheus counters."""
+
+    def test_range_plan_emits_one_key_source_span(self, fresh_obs, obs_store):
+        reg, trc = fresh_obs
+        table, store = obs_store
+        lo, hi = int(table.keys[100]), int(table.keys[900])
+        keys = store._range_keys(lo, hi)
+        query = store.query().where_range(lo, hi).where("col0", "==", 2)
+        res = query.execute()
+        (span,) = trc.spans("exec.key_source")
+        assert "exec.key_source" in obs.PROFILER_SPANS
+        assert span.args["rows"] == len(keys) == res.explain.num_keys == 800
+        assert span.args["slots"] == hi - lo
+        assert span.args["kind"] == "range" and span.args["cache"] == "miss"
+        assert span.duration == pytest.approx(res.explain.route_s)
+        assert reg.counter("deepmap_executor_key_source_rows_total").value(
+            kind="range") == len(keys)
+        assert reg.counter("deepmap_executor_key_source_slots_total").value(
+            kind="range") == hi - lo
+        query.execute()  # the plan cache holds the key stream: no walk
+        again = trc.spans("exec.key_source")[-1]
+        assert (again.args["cache"], again.args["slots"], again.args["rows"]) == (
+            "hit", 0, len(keys))
+
+    def test_host_filter_counts_every_existing_row(self, fresh_obs, obs_store):
+        reg, _ = fresh_obs
+        table, store = obs_store
+        res = store.query().where_keys(table.keys[:300]).where("col1", "<", 2).execute()
+        assert not res.explain.kernel_filtered
+        assert res.explain.filter_host_rows == 300
+        assert reg.counter("deepmap_executor_filter_host_rows_total").value(
+            kind="point") == 300
+
+    def test_kernel_filter_counts_the_aux_overridden_rows(self, fresh_obs):
+        """On the fused tier the host re-runs only the match bits of rows
+        that ``T_aux`` overrode; planting rows there moves the count by
+        exactly those rows."""
+        reg, _ = fresh_obs
+        table = make_periodic_table(n=1200, period=16, cards=(5, 3))
+        store = DeepMappingStore.build(table, DeepMappingConfig(
+            shared=(32,), private=(8,), train=TrainConfig(epochs=10, batch_size=512),
+            use_pallas=True,
+        ))
+        planted = table.keys[10:70:2]
+        store.update(planted, {"col0": np.full(30, 4, np.int32),
+                               "col1": np.full(30, 2, np.int32)})
+        lo, hi = int(table.keys[0]), int(table.keys[600])
+        in_aux = int(store.aux.contains(store._range_keys(lo, hi)).sum())
+        res = store.query().where_range(lo, hi).where("col0", "==", 4).execute()
+        assert res.explain.kernel_filtered
+        assert in_aux >= int(store.aux.contains(planted).sum()) > 0
+        assert res.explain.filter_host_rows == in_aux
+        assert reg.counter("deepmap_executor_filter_host_rows_total").value(
+            kind="range") == in_aux
+        assert set(planted.tolist()) <= set(res.keys.tolist())

@@ -8,7 +8,14 @@ out in the reference.  ``tests/test_aggregate_join.py`` and
 these functions value-for-value.
 """
 
+import operator
+
 import numpy as np
+
+#: The predicate comparisons ``ref_select`` knows, by ``Query.where`` op.
+_OPS = {"==": operator.eq, "!=": operator.ne, "<": operator.lt,
+        "<=": operator.le, ">": operator.gt, ">=": operator.ge,
+        "in": lambda v, values: v in values}
 
 
 def agg_name(func, column):
@@ -65,6 +72,21 @@ def ref_group_aggregate(columns, group_by, aggregates, sel=None):
         for j, (func, column) in enumerate(aggregates)
     }
     return groups, aggs
+
+
+def ref_select(keys, columns, lo, hi, predicates, projection):
+    """A range selection the slow, obvious way, row by row: the keys in
+    ``[lo, hi)`` whose values satisfy every ``(column, op, value)`` of
+    ``predicates``, ascending, with their values of each ``projection``
+    column.  Returns ``(keys, {column: values})``."""
+    keys = np.asarray(keys, dtype=np.int64)
+    cols = {c: np.asarray(v) for c, v in columns.items()}
+    rows = [
+        i for i in np.argsort(keys, kind="stable").tolist()
+        if lo <= int(keys[i]) < hi
+        and all(_OPS[op](cols[c][i], value) for c, op, value in predicates)
+    ]
+    return keys[rows], {c: cols[c][rows] for c in projection}
 
 
 def ref_join_mask(left_keys, key_fn, right_keys):
