@@ -569,6 +569,10 @@ class ExplainStats:
     aux_keys: int = 0
     aux_visits: int = 0
     aux_resident_keys: int = 0
+    #: The partitioned path's pool misses: partitions decompressed, and
+    #: those of them decompressed on the shared worker threads.
+    aux_decompressed: int = 0
+    aux_parallel: int = 0
     route_s: float = 0.0
     infer_s: float = 0.0
     #: The parts of ``infer_s`` spent in the ``engine.dispatch`` spans
@@ -611,6 +615,8 @@ class ExplainStats:
         self.aux_keys += other.aux_keys
         self.aux_visits += other.aux_visits
         self.aux_resident_keys += other.aux_resident_keys
+        self.aux_decompressed += other.aux_decompressed
+        self.aux_parallel += other.aux_parallel
         # one group seen by N morsels is still one group — keep the max
         self.groups_emitted = max(self.groups_emitted, other.groups_emitted)
         self.owners_failed = _union(self.owners_failed, other.owners_failed)

@@ -19,7 +19,11 @@ LRU :class:`~repro.storage.pool.MemoryPool` holds:
   search over partition-boundary keys, decompressed through the pool
   (paper §IV-B2: LRU partitions are evicted when memory is
   insufficient), and binary-searched inside, once per partition a batch
-  hits.
+  hits.  The partitions are taken in waves of at most half the pool's
+  budget decompressed; a wave's pool misses are decompressed together,
+  on a process-wide pool of worker threads (one a usable core) where
+  there are two or more, so one call holds at most half the budget
+  besides what the pool holds.
 
 Both give the same answers and count partition visits alike.
 
@@ -33,7 +37,10 @@ disk.
 
 from __future__ import annotations
 
-from typing import Dict, Optional, Tuple
+import os
+import threading
+from concurrent.futures import ThreadPoolExecutor
+from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -41,6 +48,27 @@ from repro import obs
 from repro.storage import MemoryPool, get_codec
 
 _EMPTY_I64 = np.zeros(0, dtype=np.int64)
+
+# (pid, workers, executor): see _decompress_workers.
+_WORKERS: Optional[Tuple[int, int, ThreadPoolExecutor]] = None
+_WORKERS_LOCK = threading.Lock()
+
+
+def _decompress_workers() -> Tuple[ThreadPoolExecutor, int]:
+    """The process-wide worker threads that decompress partitioned
+    probes' pool misses, one for each core this process may use, and
+    their number.  Started on first use, and again in a forked child
+    (which inherits no threads); every table and caller shares them."""
+    global _WORKERS
+    with _WORKERS_LOCK:
+        if _WORKERS is None or _WORKERS[0] != os.getpid():
+            try:
+                n = len(os.sched_getaffinity(0))
+            except AttributeError:  # a platform without affinity masks
+                n = os.cpu_count() or 1
+            _WORKERS = (os.getpid(), n,
+                        ThreadPoolExecutor(n, thread_name_prefix="aux-decompress"))
+        return _WORKERS[2], _WORKERS[1]
 
 
 def _pack_partition(keys: np.ndarray, codes: np.ndarray) -> bytes:
@@ -55,6 +83,10 @@ def _unpack_partition(blob: bytes) -> Tuple[np.ndarray, np.ndarray]:
     keys = np.frombuffer(blob[16 : 16 + 8 * n], dtype=np.int64)
     codes = np.frombuffer(blob[16 + 8 * n :], dtype=np.int32).reshape(n, m)
     return keys, codes
+
+
+def _decompress_run(codec, blobs: List[bytes]) -> List[bytes]:
+    return [codec.decompress(b) for b in blobs]
 
 
 def _answer(pkeys, pcodes, qk, idx, tomb, found, out) -> None:
@@ -131,14 +163,63 @@ class AuxTable:
         self._generation += 1
 
     # -- partition access ------------------------------------------------------
-    def _load_partition(self, idx: int) -> Tuple[np.ndarray, np.ndarray]:
-        def loader():
-            with obs.span("aux.decompress"):
-                blob = self._codec.decompress(self._partitions[idx])
-                part = _unpack_partition(blob)
-            return part, part[0].nbytes + part[1].nbytes
+    def _part_key(self, idx: int) -> tuple:
+        return ("aux", id(self), self._generation, idx)
 
-        return self.pool.get(("aux", id(self), self._generation, idx), loader)
+    def _probe_partitions(
+        self, parts: List[int], starts: List[int], ends: List[int],
+        rkeys: np.ndarray, idx: np.ndarray, tomb, found: np.ndarray, out: np.ndarray,
+    ) -> Tuple[int, int]:
+        """Answer the ordered keys ``starts[i]:ends[i]`` from partition
+        ``parts[i]``, in waves of consecutive partitions whose decompressed
+        bytes take at most half the pool's budget (one partition at the
+        least).  A wave looks each partition up in the pool; its misses
+        are decompressed under one ``aux.decompress`` span (args
+        ``parts``, and ``workers``: the worker threads they were spread
+        over, 0 where one miss was decompressed on this thread), cached
+        in key order, and every partition of the wave is then searched
+        here in order.  Returns the misses decompressed and those of them
+        decompressed on the workers."""
+        sizes = [self._part_rows[p] * (8 + 4 * self.num_values) for p in parts]
+        half = self.pool.budget_bytes // 2
+        decompressed = parallel = 0
+        lo = 0
+        while lo < len(parts):
+            hi, held = lo + 1, sizes[lo]
+            while hi < len(parts) and held + sizes[hi] <= half:
+                held += sizes[hi]
+                hi += 1
+            loaded = [self.pool.lookup(self._part_key(p)) for p in parts[lo:hi]]
+            misses = [w for w, part in enumerate(loaded) if part is None]
+            if misses:
+                todo = [parts[lo + w] for w in misses]
+                workers = 0
+                if len(todo) > 1:
+                    executor, cores = _decompress_workers()
+                    workers = min(len(todo), cores)
+                with obs.span("aux.decompress", parts=len(todo), workers=workers):
+                    blobs = [self._partitions[p] for p in todo]
+                    if workers:
+                        # One contiguous run of misses a worker: few tasks
+                        # and GIL hand-offs.  The workers run only the
+                        # codec, which drops the GIL; unpacking holds it,
+                        # so it stays on this thread.
+                        cuts = [len(todo) * i // workers for i in range(workers + 1)]
+                        runs = [executor.submit(_decompress_run, self._codec, blobs[a:b])
+                                for a, b in zip(cuts, cuts[1:])]
+                        blobs = (blob for run in runs for blob in run.result())
+                    else:
+                        blobs = map(self._codec.decompress, blobs)
+                    for w, p, blob in zip(misses, todo, blobs):
+                        part = _unpack_partition(blob)
+                        loaded[w] = self.pool.put(self._part_key(p), part,
+                                                  part[0].nbytes + part[1].nbytes)
+                decompressed += len(todo)
+                parallel += len(todo) if workers else 0
+            for part, s, e in zip(loaded, starts[lo:hi], ends[lo:hi]):
+                _answer(*part, rkeys[s:e], idx[s:e], tomb, found, out)
+            lo = hi
+        return decompressed, parallel
 
     def _decompress_all(self) -> Tuple[np.ndarray, np.ndarray]:
         """Every partition decompressed once, in key order, into one
@@ -187,14 +268,17 @@ class AuxTable:
 
         Timed by the ``aux.get`` span, whose args carry the keys probed
         (``keys``), the partitions their key ranges fall in (``visits``,
-        counted alike on both paths) and the keys answered on the
-        resident path (``resident``: all of them or none).  Those counts
-        are taken once per call: into ``deepmap_aux_keys_total{outcome}``
-        (found or absent), ``deepmap_aux_path_keys_total{path}``
-        (resident or partitioned) and
-        ``deepmap_aux_partition_visits_total``, and into ``stats`` (an
+        counted alike on both paths), the keys answered on the
+        resident path (``resident``: all of them or none), and the
+        partitioned path's pool misses: those decompressed
+        (``decompressed``) and those of them decompressed on the worker
+        threads (``parallel``).  Those counts are taken once per call:
+        into ``deepmap_aux_keys_total{outcome}`` (found or absent),
+        ``deepmap_aux_path_keys_total{path}`` (resident or partitioned)
+        and ``deepmap_aux_partition_visits_total``, and into ``stats`` (an
         :class:`~repro.api.plan.ExplainStats`: ``aux_keys``,
-        ``aux_visits``, ``aux_resident_keys``) when one is given.
+        ``aux_visits``, ``aux_resident_keys``, ``aux_decompressed``,
+        ``aux_parallel``) when one is given.
         """
         keys = np.asarray(keys, dtype=np.int64)
         n = keys.shape[0]
@@ -203,8 +287,9 @@ class AuxTable:
         if n == 0:
             return found, out
         with obs.span("aux.get") as span:
-            visits, resident = self._probe(keys, found, out)
-        span.args.update(keys=n, visits=visits, resident=resident)
+            visits, resident, decompressed, parallel = self._probe(keys, found, out)
+        span.args.update(keys=n, visits=visits, resident=resident,
+                         decompressed=decompressed, parallel=parallel)
         hits = int(np.count_nonzero(found))
         reg = obs.registry()
         probed = reg.counter(
@@ -228,13 +313,16 @@ class AuxTable:
             stats.aux_keys += n
             stats.aux_visits += visits
             stats.aux_resident_keys += resident
+            stats.aux_decompressed += decompressed
+            stats.aux_parallel += parallel
         return found, out
 
     def _probe(
         self, keys: np.ndarray, found: np.ndarray, out: np.ndarray
-    ) -> Tuple[int, int]:
+    ) -> Tuple[int, int, int, int]:
         """Fill ``found``/``out`` for ``keys``; returns the partitions
-        visited and the keys answered on the resident path."""
+        visited, the keys answered on the resident path, and the pool
+        misses decompressed, all and on the worker threads."""
         view = self._resident_view() if self._partitions else None
         resident = keys.shape[0] if view is not None else 0
 
@@ -247,7 +335,7 @@ class AuxTable:
                     out[i] = row
         remaining = np.flatnonzero(~found)
         if not remaining.size or not self._partitions:
-            return 0, resident
+            return 0, resident, 0, 0
         tomb = (
             np.fromiter(self._tombstones, dtype=np.int64, count=len(self._tombstones))
             if self._tombstones
@@ -267,12 +355,11 @@ class AuxTable:
 
         if view is not None:
             _answer(*view, rkeys, remaining, tomb, found, out)
-        else:
-            ends = np.append(starts[1:], pid.size)
-            for s, e in zip(starts.tolist(), ends.tolist()):
-                _answer(*self._load_partition(int(pid[s])), rkeys[s:e],
-                        remaining[s:e], tomb, found, out)
-        return int(starts.size), resident
+            return int(starts.size), resident, 0, 0
+        ends = np.append(starts[1:], pid.size)
+        return (int(starts.size), resident) + self._probe_partitions(
+            pid[starts].tolist(), starts.tolist(), ends.tolist(),
+            rkeys, remaining, tomb, found, out)
 
     def contains(self, keys: np.ndarray) -> np.ndarray:
         return self.get(keys)[0]

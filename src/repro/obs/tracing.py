@@ -62,7 +62,7 @@ PROFILER_SPANS = (
     "engine.wait",      # InferenceEngine.collect: blocked on device outputs
     "store.exist",      # host BitVector.test fallback, only when it runs
     "aux.get",          # AuxTable.get
-    "aux.decompress",   # inside aux.get: the pool's loader on a miss
+    "aux.decompress",   # inside aux.get: one wave of pool misses, or a view build
     "store.filter",     # predicate filter on aux-corrected codes
     "store.decode",     # decode of the selected columns
     "exec.agg",         # code-space group-by fold of one chunk

@@ -20,6 +20,7 @@ from __future__ import annotations
 
 import dataclasses
 import gzip
+import threading
 import zlib
 from typing import Callable, Dict
 
@@ -73,6 +74,19 @@ def _fallback(canonical_name: str, native_magic: bytes, level: int) -> Codec:
     return Codec(f"{canonical_name}(zlib-fallback)", comp, decomp)
 
 
+_ZSTD_LOCAL = threading.local()
+
+
+def _zstd_decompressor():
+    """This thread's zstd decompression context, made on its first use:
+    one context serves every frame a thread decompresses (contexts are
+    costly to make, and not safe to share between threads)."""
+    dctx = getattr(_ZSTD_LOCAL, "dctx", None)
+    if dctx is None:
+        dctx = _ZSTD_LOCAL.dctx = zstandard.ZstdDecompressor()
+    return dctx
+
+
 def _zstd(level: int) -> Codec:
     name = f"zstd{'' if level == 3 else level}"
     if not HAVE_ZSTD:
@@ -84,7 +98,7 @@ def _zstd(level: int) -> Codec:
     def decomp(data: bytes) -> bytes:
         if data[:1] and data[0] == _ZLIB_FIRST_BYTE and not data.startswith(_ZSTD_MAGIC):
             return zlib.decompress(data)  # written by the fallback
-        return zstandard.ZstdDecompressor().decompress(data)
+        return _zstd_decompressor().decompress(data)
 
     return Codec(name, comp, decomp)
 

@@ -44,15 +44,34 @@ class MemoryPool:
         return self._used
 
     def get(self, key: Hashable, loader: Callable[[], Tuple[object, int]]):
+        obj = self.lookup(key)
+        if obj is None:
+            obj = self.put(key, *loader())
+        return obj
+
+    def lookup(self, key: Hashable):
+        """The cached object under ``key`` (a hit, now most recently
+        used), or None (a miss); never loads."""
+        with self._lock:
+            entry = self._entries.get(key)
+            if entry is None:
+                self.misses += 1
+                return None
+            self._entries.move_to_end(key)
+            self.hits += 1
+            return entry[0]
+
+    def put(self, key: Hashable, obj, nbytes: int):
+        """Cache ``obj`` under ``key``, charged ``nbytes``, evicting
+        least-recently-used entries until the budget holds, and return
+        it.  Where another caller cached ``key`` meanwhile, that object
+        is kept and returned; one over the whole budget is returned
+        uncached."""
         with self._lock:
             entry = self._entries.get(key)
             if entry is not None:
                 self._entries.move_to_end(key)
-                self.hits += 1
                 return entry[0]
-            self.misses += 1
-        obj, nbytes = loader()
-        with self._lock:
             if nbytes > self.budget_bytes:
                 return obj  # uncacheable: stream through
             while self._used + nbytes > self.budget_bytes and self._entries:

@@ -240,7 +240,10 @@ class TestProbePaths:
         resident = probe.size if path == "resident" else 0
         assert (stats.aux_keys, stats.aux_visits, stats.aux_resident_keys) == (6, 3, resident)
         (span,) = trc.spans("aux.get")
-        assert span.args == {"keys": 6, "visits": 3, "resident": resident}
+        # over the pool, each 80-byte partition is a wave of its own (half
+        # the 200-byte budget holds one): three misses, each inline
+        assert span.args == {"keys": 6, "visits": 3, "resident": resident,
+                             "decompressed": 0 if resident else 3, "parallel": 0}
         by_path = reg.counter("deepmap_aux_path_keys_total")
         assert by_path.value(path="resident") == resident
         assert by_path.value(path="partitioned") == probe.size - resident
@@ -293,3 +296,233 @@ class TestProbePaths:
         np.testing.assert_array_equal(got[1:], codes[1:])
         assert stats.aux_resident_keys == keys.size
         assert aux.pool.used_bytes == (keys.size - 1) * (8 + 4 * 3)
+
+
+#: 2,000 rows of 20 bytes in 1,020-byte partitions: 40 partitions and
+#: 40,000 bytes decompressed.  The pool holds a quarter of that, and half
+#: of it (5,000 bytes) makes a wave of four partitions.
+OVER = dict(n=2000, partition_bytes=1024)
+OVER_POOL = 10_000
+PART_BYTES = 51 * 20
+
+
+class _RecordingPool(MemoryPool):
+    """A pool that records its used bytes after every insertion."""
+
+    def __init__(self, budget):
+        super().__init__(budget)
+        self.used_after_put = []
+
+    def put(self, key, obj, nbytes):
+        obj = super().put(key, obj, nbytes)
+        self.used_after_put.append(self.used_bytes)
+        return obj
+
+
+def _accounted(pool):
+    return sum(n for _, n in pool._entries.values())
+
+
+def _mutate(case, keys, tables):
+    """Apply ``case``'s overlay to every table; returns the queries, the
+    delta rows and the tombstoned keys."""
+    rng = np.random.default_rng(11)
+    delta, dead = {}, []
+    if case == "delta_overlay":
+        delta = {int(keys[7]): np.array([5, 5, 5], np.int32),
+                 int(keys[-1]) + 3: np.array([1, 2, 3], np.int32)}
+    if case == "tombstones":
+        dead = [int(k) for k in keys[300:360]]
+    for aux in tables:
+        if delta:
+            aux.add(np.array(list(delta), np.int64), np.stack(list(delta.values())))
+        if dead:
+            aux.remove(np.array(dead, np.int64))
+    inside_absent = np.setdiff1d(np.arange(keys[0], keys[-1]), keys)
+    q = {
+        "present_sorted": keys,
+        "unsorted_duplicates": rng.permutation(np.concatenate([keys, keys[::3]])),
+        "absent": np.concatenate([inside_absent[::17], [keys[-1] + 1, 10**9]]),
+        "below_first_partition": np.concatenate([[keys[0] - 1, -5], keys[:30]]),
+        "delta_overlay": rng.permutation(np.concatenate([keys[::2], list(delta)])),
+        "tombstones": rng.permutation(keys),
+    }[case]
+    return np.asarray(q, np.int64), delta, dead
+
+
+class TestParallelDecompress:
+    """The partitioned path decompresses a wave's pool misses together on
+    the shared worker threads, and answers as the resident path and a
+    per-key reference do, under the pool's budget."""
+
+    @pytest.mark.parametrize("case", ["present_sorted", "unsorted_duplicates", "absent",
+                                      "below_first_partition", "delta_overlay",
+                                      "tombstones"])
+    def test_answers_as_the_resident_path_and_the_reference(self, case):
+        keys, codes, over = make_aux(pool=MemoryPool(OVER_POOL), **OVER)
+        _, _, resident = make_aux(pool=MemoryPool(1 << 30), **OVER)
+        assert len(over._partitions) == 40 and over._compacted_rows * 20 > 2 * OVER_POOL
+        q, delta, dead = _mutate(case, keys, (over, resident))
+        want_found, want_codes, want_visits = _reference_probe(keys, codes, over, delta,
+                                                               dead, q)
+        stats, res_stats = ExplainStats(), ExplainStats()
+        found, got = over.get(q, stats)
+        res_found, res_got = resident.get(q, res_stats)
+        np.testing.assert_array_equal(found, want_found)
+        np.testing.assert_array_equal(got[found], want_codes)
+        np.testing.assert_array_equal(res_found, found)
+        np.testing.assert_array_equal(res_got[res_found], got[found])
+        assert stats.aux_resident_keys == 0 and res_stats.aux_resident_keys == q.size
+        assert stats.aux_visits == res_stats.aux_visits == want_visits
+        # a cold pool: every visit misses; the first wave holds two or
+        # more of them wherever two partitions are visited
+        assert stats.aux_decompressed == want_visits
+        assert (stats.aux_parallel > 0) == (want_visits > 1)
+        assert (res_stats.aux_decompressed, res_stats.aux_parallel) == (0, 0)
+
+    def test_pool_budget_and_wave_bytes_hold(self):
+        from repro import obs
+
+        pool = _RecordingPool(OVER_POOL)
+        keys, codes, aux = make_aux(pool=pool, **OVER)
+        rng = np.random.default_rng(3)
+        trc = obs.Tracer()
+        prev = obs.set_tracer(trc)
+        try:
+            for q in (keys, keys, rng.choice(keys, 700), keys[::-1]):
+                found, got = aux.get(q)
+                assert found.all()
+        finally:
+            obs.set_tracer(prev)
+        assert pool.used_after_put and max(pool.used_after_put) <= OVER_POOL
+        assert pool.used_bytes == _accounted(pool) <= OVER_POOL
+        waves = trc.spans("aux.decompress")
+        assert waves and all(w.parent == "aux.get" for w in waves)
+        assert max(w.args["parts"] for w in waves) * PART_BYTES <= OVER_POOL // 2
+        decompressed = sum(s.args["decompressed"] for s in trc.spans("aux.get"))
+        assert sum(w.args["parts"] for w in waves) == decompressed == pool.misses
+
+    def test_span_args_and_stats_count_misses_and_workers(self):
+        from repro import obs
+        from repro.core.aux_table import _decompress_workers
+
+        keys, codes, aux = make_aux(pool=MemoryPool(OVER_POOL), **OVER)
+        first_four = keys[: 4 * 51]                       # partitions 0-3: one wave
+        calls = [first_four,                              # four misses, on the workers
+                 first_four,                              # four hits
+                 np.concatenate([first_four, keys[510:513]])]  # hits, then one miss
+        trc = obs.Tracer()
+        prev = obs.set_tracer(trc)
+        got_stats = []
+        try:
+            for q in calls:
+                stats = ExplainStats()
+                found, got = aux.get(q, stats)
+                assert found.all()
+                np.testing.assert_array_equal(got, codes[np.searchsorted(keys, q)])
+                got_stats.append(stats)
+        finally:
+            obs.set_tracer(prev)
+        counts = [(s.aux_visits, s.aux_decompressed, s.aux_parallel) for s in got_stats]
+        assert counts == [(4, 4, 4), (4, 0, 0), (5, 1, 0)]
+        spans = trc.spans("aux.get")
+        assert [(s.args["decompressed"], s.args["parallel"]) for s in spans] == [
+            (4, 4), (0, 0), (1, 0)]
+        waves = trc.spans("aux.decompress")
+        cores = _decompress_workers()[1]
+        assert [(w.args["parts"], w.args["workers"]) for w in waves] == [
+            (4, min(4, cores)), (1, 0)]
+        merged = ExplainStats()
+        for s in got_stats:
+            merged.merge_timings(s)
+        assert (merged.aux_decompressed, merged.aux_parallel) == (5, 4)
+
+    def test_one_miss_decompresses_inline(self, monkeypatch):
+        from repro.core import aux_table
+
+        keys, codes, aux = make_aux(pool=MemoryPool(OVER_POOL), **OVER)
+
+        def no_workers():
+            raise AssertionError("one miss must not reach the worker threads")
+
+        monkeypatch.setattr(aux_table, "_decompress_workers", no_workers)
+        counts = []
+        for _ in range(2):                                # a miss, then a hit
+            stats = ExplainStats()
+            found, got = aux.get(keys[:5], stats)
+            assert found.all()
+            np.testing.assert_array_equal(got, codes[:5])
+            counts.append((stats.aux_decompressed, stats.aux_parallel))
+        assert counts == [(1, 0), (0, 0)]
+        assert (aux.pool.misses, aux.pool.hits) == (1, 1)
+
+    def test_concurrent_callers_answer_correctly(self):
+        import os
+        import sys
+        import threading
+
+        keys, codes, aux = make_aux(pool=MemoryPool(OVER_POOL), **OVER)
+        lut = dict(zip(keys.tolist(), map(tuple, codes.tolist())))
+        absent = np.setdiff1d(np.arange(keys[0], keys[-1]), keys)
+        errors, done = [], []
+
+        def caller(seed):
+            rng = np.random.default_rng(seed)
+            try:
+                for _ in range(4):
+                    q = rng.permutation(np.concatenate(
+                        [rng.choice(keys, 600), rng.choice(absent, 60)]))
+                    found, got = aux.get(q)
+                    assert found.tolist() == [int(k) in lut for k in q]
+                    assert [tuple(r) for r in got[found].tolist()] == [
+                        lut[int(k)] for k in q[found]]
+                done.append(seed)
+            except Exception as e:  # reported below, with its caller
+                errors.append((seed, repr(e)))
+
+        n = 2 * (os.cpu_count() or 4)
+        threads = [threading.Thread(target=caller, args=(i,)) for i in range(n)]
+        interval = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        try:
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=120)
+        finally:
+            sys.setswitchinterval(interval)
+        assert not any(t.is_alive() for t in threads)
+        assert not errors and sorted(done) == list(range(n))
+        assert aux.pool.used_bytes == _accounted(aux.pool) <= OVER_POOL
+
+    def test_put_of_a_cached_key_keeps_the_first(self):
+        pool = MemoryPool(100)
+        assert pool.put("k", "first", 30) == "first"
+        assert pool.put("k", "second", 30) == "first"
+        assert pool.used_bytes == 30 and pool.lookup("k") == "first"
+        assert pool.lookup("absent") is None and (pool.hits, pool.misses) == (1, 1)
+        assert pool.put("huge", "streamed", 101) == "streamed" and pool.used_bytes == 30
+
+
+def test_zstd_context_is_one_a_thread():
+    import threading
+
+    pytest.importorskip("zstandard")
+    from repro.storage import codecs
+
+    codec = codecs.get_codec("zstd")
+    blob = codec.compress(b"deepmapping" * 1000)
+    mine = codecs._zstd_decompressor()
+    assert codecs._zstd_decompressor() is mine
+    seen = []
+
+    def other():
+        seen.append((codecs._zstd_decompressor(), codec.decompress(blob)))
+
+    t = threading.Thread(target=other)
+    t.start()
+    t.join(timeout=30)
+    assert not t.is_alive()
+    ((theirs, data),) = seen
+    assert theirs is not mine and data == b"deepmapping" * 1000
+    assert codec.decompress(blob) == data
