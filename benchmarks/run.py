@@ -29,8 +29,6 @@ Sections:
             hot-path comparison; writes BENCH_lookup.json at the repo
             root (p50/p99 latency, QPS, compile counts) — the CI
             smoke-bench job uploads it as the perf-trajectory artifact
-  tokens  — beyond-paper: DeepMapping-compressed LM data pipeline
-  roofline — assignment §Roofline terms from the dry-run records
 """
 
 from __future__ import annotations
@@ -56,13 +54,12 @@ def main() -> None:
     )
     args = ap.parse_args()
 
-    from repro.launch.compile_cache import enable_compile_cache
+    from repro.compile_cache import enable_compile_cache
 
     enable_compile_cache(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
     from benchmarks import bench_beyond, bench_breakdown, bench_lookup
     from benchmarks import bench_mhas, bench_modify, bench_query, bench_shards
-    from benchmarks import roofline
     from benchmarks import common as C
 
     datasets = list(C.DATASETS) if args.full else list(C.FAST_DATASETS)
@@ -107,13 +104,7 @@ def main() -> None:
                 mesh=bench_shards.run_mesh(smoke=args.smoke),
             )
         ),
-        # lazy: bench_tokens hard-imports zstandard (optional elsewhere);
-        # a host without it should still run every other section
-        "tokens": lambda: __import__(
-            "benchmarks.bench_tokens", fromlist=["run"]
-        ).run(),
         "beyond": lambda: bench_beyond.run(),
-        "roofline": lambda: roofline.run(),
     }
     wanted = args.sections or list(sections)
     failures = 0

@@ -406,7 +406,7 @@ def main() -> int:
                          "the run still exits 1")
     args = ap.parse_args()
 
-    from repro.launch.compile_cache import enable_compile_cache
+    from repro.compile_cache import enable_compile_cache
 
     print(f"compile cache: {enable_compile_cache(ROOT)}", flush=True)
     dev = jax.devices()[0]

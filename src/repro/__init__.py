@@ -1,5 +1,6 @@
 """repro — DeepMapping: learned data mapping for lossless compression and
-efficient lookup, built as a multi-pod JAX training/inference framework.
+efficient lookup, as a key-value and analytics store whose hot path runs
+on the TPU.
 
 Top-level entrypoints (lazy — see ``repro.api.entry``):
 
@@ -13,19 +14,21 @@ Subpackages:
                         layer shared by every store implementation.
 - ``repro.core``      — the paper's hybrid learned structure (model, T_aux,
                         V_exist, f_decode, MHAS search, modifications).
+- ``repro.cluster``   — range/hash-sharded stores and the mesh scatter.
 - ``repro.baselines`` — AB/ABC/HB/HBC comparison stores.
-- ``repro.data``      — dataset generators + token stores.
-- ``repro.models``    — the assigned LM architectures.
-- ``repro.train``     — optimizer/checkpoint/fault-tolerance substrate.
-- ``repro.serve``     — serving engines (decode step, lookup server).
-- ``repro.sharding``  — mesh partitioning rules.
+- ``repro.data``      — dataset generators.
+- ``repro.serve``     — the batched lookup server.
 - ``repro.kernels``   — Pallas TPU kernels for the lookup hot path.
-- ``repro.launch``    — mesh factory, dry-run driver, train/serve entry.
-- ``repro.configs``   — per-architecture configs (exact + smoke).
+- ``repro.storage``   — codecs and the partition memory pool.
+- ``repro.fault``     — typed failures, retries, health and injection.
+- ``repro.obs``       — metrics, spans and their exporters.
+
+``repro.compile_cache`` points the entry points' persistent XLA cache at
+the checkout.
 
 Import of this package must stay side-effect free w.r.t. JAX device state:
-never touch ``jax.devices()`` at import time (the dry-run pins a 512-device
-host platform before importing us).
+never touch ``jax.devices()`` at import time, so a caller can still set
+the platform or a virtual device count first.
 """
 
 __version__ = "1.0.0"
@@ -33,7 +36,7 @@ __version__ = "1.0.0"
 
 def __getattr__(name):
     # Lazy so `import repro` never drags in JAX (keeps import
-    # side-effect free for the dry-run's device pinning).
+    # side-effect free for a caller's device pinning).
     if name in ("open", "build"):
         from repro.api import entry
 

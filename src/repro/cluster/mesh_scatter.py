@@ -5,7 +5,7 @@ halves, but every shard's device inference still runs through one
 device queue.  When real devices exist (or virtual ones, via
 ``XLA_FLAGS=--xla_force_host_platform_device_count=N``), this module
 maps the K shards onto a 1-D ``("shard",)`` mesh
-(:func:`repro.launch.mesh.make_shard_mesh`) and answers a scattered
+(:func:`make_shard_mesh`) and answers a scattered
 lookup batch in ONE ``shard_map`` launch: each device runs the model
 forward + packed-word existence test for its ``ceil(K/N)`` shards
 (``vmap`` over the local shard block), and an ``all_gather`` collects
@@ -47,11 +47,21 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro import obs
 from repro.core.inference import INT32_MAX, InferTicket
 from repro.kernels import bitvector as bv_kernel
-from repro.launch import mesh as mesh_lib
 
 #: Minimum padded batch length — one lane-ish tile, keeps tiny batches
 #: from compiling one program per length.
 MIN_BATCH_PAD = 128
+
+
+def make_shard_mesh(n: int | None = None):
+    """1-D ``("shard",)`` mesh over the host's devices — the cluster
+    scatter lays K store shards out along this axis, ``ceil(K / n)`` per
+    device.  ``n`` caps the device count (default: all devices,
+    including ``xla_force_host_platform_device_count``-virtualized
+    ones)."""
+    n_dev = len(jax.devices())
+    n = n_dev if n is None else max(1, min(int(n), n_dev))
+    return jax.make_mesh((n,), ("shard",), axis_types=(jax.sharding.AxisType.Auto,))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -203,7 +213,7 @@ class MeshShardRunner:
                 or sp.dtype != first.dtype
             ):
                 return None
-        mesh = mesh_lib.make_shard_mesh()
+        mesh = make_shard_mesh()
         return cls(shards, mesh, n_dev)
 
     @staticmethod

@@ -26,8 +26,8 @@ from repro.core.encoding import KeyEncoder, build_codecs, onehot_digits
 from repro.core.mhas import controller as ctrl_lib
 from repro.core.mhas.search_space import SearchSpace
 from repro.core.model import MLPSpec
+from repro.core.optimizer import adam_init, adam_update
 from repro.core.table import Table
-from repro.train.optimizer import adam_init, adam_update
 
 
 @dataclasses.dataclass(frozen=True)

@@ -19,7 +19,7 @@ import numpy as np
 
 from repro.core import model as model_lib
 from repro.core.model import MLPSpec
-from repro.train.optimizer import OptState, adam_init, adam_update, exponential_decay
+from repro.core.optimizer import OptState, adam_init, adam_update, exponential_decay
 
 
 @dataclasses.dataclass(frozen=True)

@@ -1,6 +1,5 @@
 """Dataset substrate: synthetic workload generators mirroring the
-paper's evaluation datasets (§V-A1) plus token stores and loaders for
-the LM training pipeline."""
+paper's evaluation datasets (§V-A1)."""
 
 from repro.data.datasets import (  # noqa: F401
     cropland_like,

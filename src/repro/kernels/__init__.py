@@ -17,8 +17,7 @@ memorization MLP — dominates device time.  Kernels:
 
 ``ops`` holds the jit'd public wrappers with MXU-alignment padding and
 the VMEM-budget check.  Kernels are validated in ``interpret=True`` on
-CPU; the dry-run path never traces them (pure-jnp path is used when
-lowering for the virtual-device mesh).
+CPU.
 """
 
 from repro.kernels.ops import (  # noqa: F401

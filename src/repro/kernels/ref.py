@@ -1,9 +1,7 @@
 """Pure-jnp oracles for every kernel in this package.
 
 These define the mathematical contract: kernels must ``allclose`` these
-over shape/dtype sweeps (see ``tests/test_kernels.py``).  The oracles
-are also the path used by the multi-pod dry-run lowering (kernels are
-TPU-target; the virtual-device mesh compiles the oracle graph).
+over shape/dtype sweeps (see ``tests/test_kernels.py``).
 """
 
 from __future__ import annotations

@@ -1,5 +1,4 @@
-"""Serving substrate: prefill/decode steps for the LM architectures and
-the DeepMapping batched lookup server (the paper's deployment)."""
+"""Serving: the DeepMapping batched lookup server (the paper's
+deployment)."""
 
-from repro.serve.serve_step import make_decode_step, make_prefill_step  # noqa: F401
 from repro.serve.engine import LookupServer  # noqa: F401

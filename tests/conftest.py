@@ -1,6 +1,6 @@
 """Shared fixtures. NOTE: no XLA device-count overrides here — smoke
-tests and benches must see the single real CPU device; only the dry-run
-(separate process) pins 512 virtual devices."""
+tests and benches must see the single real CPU device; multi-device
+cases get virtual devices from the caller's ``XLA_FLAGS``."""
 
 import numpy as np
 import pytest

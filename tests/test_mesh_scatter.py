@@ -16,7 +16,7 @@ import jax
 
 from conftest import make_periodic_table, make_random_table
 from repro.cluster import ClusterConfig, ShardedDeepMappingStore
-from repro.cluster.mesh_scatter import MeshShardRunner, _pow2_at_least
+from repro.cluster.mesh_scatter import MeshShardRunner, _pow2_at_least, make_shard_mesh
 from repro.core import DeepMappingConfig
 from repro.core.trainer import TrainConfig
 from repro.kernels import bitvector as bv_kernel
@@ -66,6 +66,14 @@ class TestUnits:
         assert _pow2_at_least(128, 128) == 128
         assert _pow2_at_least(129, 128) == 256
         assert _pow2_at_least(1000, 128) == 1024
+
+    def test_make_shard_mesh(self):
+        mesh = make_shard_mesh()
+        assert mesh.axis_names == ("shard",)
+        assert mesh.devices.shape == (N_DEV,)
+        assert make_shard_mesh(1).devices.shape == (1,)
+        assert make_shard_mesh(0).devices.shape == (1,)
+        assert make_shard_mesh(N_DEV + 5).devices.shape == (N_DEV,)
 
     def test_pack_words32_layout(self):
         words = np.arange(4, dtype=np.uint64)

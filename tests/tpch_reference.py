@@ -100,6 +100,31 @@ def ref_join_mask(left_keys, key_fn, right_keys):
     return np.asarray([int(k) in right for k in probe.tolist()], dtype=bool)
 
 
+def ref_lookup(keys, columns, probe, projection=None):
+    """Point lookups the slow, obvious way, through a python dict from
+    key to row.  Returns ``({column: values}, exists)`` aligned with
+    ``probe``; an absent key's values are the column's first value
+    (callers compare present rows only)."""
+    row = {int(k): i for i, k in enumerate(np.asarray(keys, dtype=np.int64).tolist())}
+    pos = [row.get(int(k), -1) for k in np.asarray(probe, dtype=np.int64).tolist()]
+    exists = np.asarray([p >= 0 for p in pos], dtype=bool)
+    take = [max(p, 0) for p in pos]
+    names = tuple(columns) if projection is None else projection
+    return {c: np.asarray(columns[c])[take] for c in names}, exists
+
+
+def ref_join(left_keys, key_fn, right_keys, right_columns):
+    """The inner key-equi join the slow, obvious way: the left keys,
+    ascending, whose mapped key exists on the right, and each right
+    column's value at the mapped key.  Returns ``(keys, {column:
+    values})``."""
+    left = np.sort(np.asarray(left_keys, dtype=np.int64))
+    keys = left[ref_join_mask(left, key_fn, right_keys)]
+    probe = keys if key_fn is None else np.asarray(key_fn(keys), dtype=np.int64)
+    values, _ = ref_lookup(right_keys, right_columns, probe)
+    return keys, values
+
+
 def norm_strings(arr):
     """Normalize a (possibly bytes-decoded) string column for
     comparison: everything through ``astype(str)``."""

@@ -39,12 +39,9 @@ ALLOWED: Dict[str, Tuple[str, ...]] = {
         "repro.api.protocol",
         "repro.api.cache",
         "repro.kernels",
-        "repro.models",
-        "repro.train",
         "repro.data",
         "repro.obs",
         "repro.storage",
-        "repro.configs",
         "repro.fault",
     ),
     "repro.baselines": (
@@ -58,13 +55,8 @@ ALLOWED: Dict[str, Tuple[str, ...]] = {
         "repro.api",
         "repro.core",
         "repro.kernels",
-        # mesh_scatter lays shard fleets out on launch-layer meshes
-        # (make_shard_mesh); launch stays a leaf w.r.t. repro.cluster.
-        "repro.launch",
-        "repro.models",
         "repro.obs",
         "repro.storage",
-        "repro.sharding",
         "repro.fault",
     ),
     "repro.serve": (
@@ -72,7 +64,6 @@ ALLOWED: Dict[str, Tuple[str, ...]] = {
         "repro.core",
         "repro.cluster",
         "repro.kernels",
-        "repro.models",
         "repro.obs",
         "repro.storage",
         "repro.fault",
