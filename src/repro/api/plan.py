@@ -563,12 +563,14 @@ class ExplainStats:
     #: Probe keys scattered into the right store's existence index by
     #: a ``join(...)`` plan (summed across morsels).
     join_probes: int = 0
-    #: Keys probed in ``T_aux``, partitions visited for them, and the
-    #: keys of those answered from the pool-resident sorted view, as
-    #: ``AuxTable.get`` counts them.
+    #: Keys probed in ``T_aux``, partitions visited for them, the keys
+    #: of those answered from the pool-resident sorted view, and those
+    #: of them whose correction the device applied from its copy of the
+    #: view, as ``AuxTable.get`` and ``AuxTable.merge_device`` count them.
     aux_keys: int = 0
     aux_visits: int = 0
     aux_resident_keys: int = 0
+    aux_device_keys: int = 0
     #: The partitioned path's pool misses: partitions decompressed, and
     #: those of them decompressed on the shared worker threads.
     aux_decompressed: int = 0
@@ -615,6 +617,7 @@ class ExplainStats:
         self.aux_keys += other.aux_keys
         self.aux_visits += other.aux_visits
         self.aux_resident_keys += other.aux_resident_keys
+        self.aux_device_keys += other.aux_device_keys
         self.aux_decompressed += other.aux_decompressed
         self.aux_parallel += other.aux_parallel
         # one group seen by N morsels is still one group — keep the max

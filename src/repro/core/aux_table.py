@@ -27,6 +27,12 @@ LRU :class:`~repro.storage.pool.MemoryPool` holds:
 
 Both give the same answers and count partition visits alike.
 
+Where the resident view exists, :meth:`AuxTable.device_view` also keeps
+a copy of it in device memory, and the inference engine corrects the
+model's codes from that copy on the device, before they come back to
+the host (Algorithm 1, lines 6-8); :meth:`AuxTable.merge_device` is the
+host half of such a probe: the delta overlay and the counts.
+
 Modifications (Algorithms 3–5) land in a sorted in-memory delta overlay
 (inserts/updates) and a tombstone set (deletes of rows that live in
 compacted partitions); ``compact()`` folds both back into partitions
@@ -37,17 +43,21 @@ disk.
 
 from __future__ import annotations
 
+import dataclasses
 import os
 import threading
 from concurrent.futures import ThreadPoolExecutor
 from typing import Dict, List, Optional, Tuple
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro import obs
 from repro.storage import MemoryPool, get_codec
 
 _EMPTY_I64 = np.zeros(0, dtype=np.int64)
+_INT32_MAX = 2**31 - 1
 
 # (pid, workers, executor): see _decompress_workers.
 _WORKERS: Optional[Tuple[int, int, ThreadPoolExecutor]] = None
@@ -101,6 +111,78 @@ def _answer(pkeys, pcodes, qk, idx, tomb, found, out) -> None:
     out[sel] = pcodes[pos[hit]]
 
 
+@jax.jit
+def _aux_correct(codes, keys, bits, ranks, cols):
+    """The ``T_aux`` override on the device, over a :class:`DeviceView`'s
+    ``bits`` and ``ranks`` and the code arrays ``cols`` of the wanted
+    heads: each key of ``keys`` (int32, -1 padding) that is a view key
+    takes its row's codes in place of the model's ``codes`` (one array,
+    or one per streamed page, side by side).  Returns the corrected
+    codes and the hit mask."""
+    if isinstance(codes, tuple):
+        codes = jnp.concatenate(codes, axis=1)
+    w = jnp.clip(keys >> 5, 0, bits.shape[0] - 1)   # past the keys: the 0 word
+    word = bits[w]
+    b = (keys & 31).astype(jnp.uint32)
+    hit = (keys >= 0) & (((word >> b) & 1) == 1)
+    below = jax.lax.population_count(word & ((jnp.uint32(1) << b) - 1))
+    row = jnp.where(hit, ranks[w] + below.astype(jnp.int32), 0)
+    fixed = jnp.where(hit[:, None], jnp.stack([c[row] for c in cols], axis=1), codes)
+    return fixed, hit
+
+
+@dataclasses.dataclass(frozen=True)
+class DeviceView:
+    """A device copy of one generation of the resident sorted view, laid
+    out so a probe gathers few elements a key (a device gather costs per
+    element, and a binary search over the keys takes ~23 of them):
+
+    - ``bits (W + 1,) uint32``: bit ``k % 32`` of word ``k // 32`` is set
+      where ``k`` is a view key; the last word is 0, for keys above
+      every view key;
+    - ``ranks (W + 1,) int32``: the view keys below word ``w``'s first,
+      so a present key's row is ``ranks[w]`` plus the set bits below it;
+    - ``codes``: one ``(rows,) int32`` array a head, so a probe gathers
+      only the heads it wants."""
+
+    generation: int
+    bits: jax.Array
+    ranks: jax.Array
+    codes: Tuple[jax.Array, ...]
+
+    @property
+    def signature(self) -> tuple:
+        """The shapes the correction's compiled program depends on,
+        besides the caller's codes, keys and heads."""
+        return (self.bits.shape[0], self.codes[0].shape[0])
+
+    def correct(self, codes, keys, heads: Tuple[int, ...]):
+        """Enqueue the override (:func:`_aux_correct`) of the model's
+        device ``codes`` for the int32 ``keys`` (-1 padding), whose
+        columns are the heads ``heads`` (canonical indices); returns the
+        device arrays ``(corrected codes, hit mask)``."""
+        return _aux_correct(codes, keys, self.bits, self.ranks,
+                            tuple(self.codes[h] for h in heads))
+
+
+def _device_copy(keys, codes, generation) -> Optional[DeviceView]:
+    """Upload the resident view ``(keys, codes)`` as a :class:`DeviceView`;
+    None where a key lies outside ``[0, INT32_MAX]`` (the engine's int32
+    key buffer could not match it) or the bitmap would outweigh the
+    host's key column (keys too sparse)."""
+    nwords = int(keys[-1]) // 32 + 1
+    if keys[0] < 0 or keys[-1] > _INT32_MAX or 8 * (nwords + 1) > keys.nbytes:
+        return None
+    w = keys >> 5
+    # distinct powers of two below 2**32 add exactly in float64
+    bits = np.bincount(w, weights=np.left_shift(1, keys & 31).astype(np.float64),
+                       minlength=nwords + 1).astype(np.uint32)
+    counts = np.bincount(w, minlength=nwords + 1)
+    ranks = (np.cumsum(counts) - counts).astype(np.int32)
+    return DeviceView(generation, jnp.asarray(bits), jnp.asarray(ranks), tuple(
+        jnp.asarray(np.ascontiguousarray(codes[:, j])) for j in range(codes.shape[1])))
+
+
 class AuxTable:
     """Sorted / partitioned / compressed misclassified-row store."""
 
@@ -126,6 +208,9 @@ class AuxTable:
         self._tombstones: set[int] = set()
         self._delta_size_cache: Optional[int] = None
         self._generation = 0  # pool-key namespace; bumped by compact()
+        # (generation, its copy or None); guarded-by: _device_lock
+        self._device: Optional[Tuple[int, Optional[DeviceView]]] = None
+        self._device_lock = threading.Lock()
 
     # -- construction --------------------------------------------------------
     @classmethod
@@ -160,6 +245,8 @@ class AuxTable:
         self._boundaries = np.asarray(bounds, dtype=np.int64)
         self._compacted_rows = int(keys.shape[0])
         self.pool.invalidate(self._view_key())
+        with self._device_lock:
+            self._device = None
         self._generation += 1
 
     # -- partition access ------------------------------------------------------
@@ -253,6 +340,34 @@ class AuxTable:
             )
         return view
 
+    def device_view(self) -> Optional[DeviceView]:
+        """The device copy of the resident view (:func:`_device_copy`),
+        uploaded once a generation; None where the host path has to
+        answer: no compacted row, a view that does not fit the pool (the
+        copy leaves the device with it), one the copy cannot hold, or
+        tombstones, which the copy does not hold.
+
+        While it is given, every existing key above ``INT32_MAX`` lives
+        in the delta overlay: the model cannot answer it and no
+        compacted row holds it, so :meth:`merge_device` answers it."""
+        if self._tombstones or not self._partitions:
+            return None
+        view = self._resident_view()
+        with self._device_lock:
+            if view is None:
+                self._device = None
+                return None
+            if self._device is None or self._device[0] != self._generation:
+                self._device = (self._generation,
+                                _device_copy(*view, self._generation))
+            return self._device[1]
+
+    def device_current(self, view: Optional[DeviceView]) -> bool:
+        """True while ``view`` still answers as the host path would: the
+        table is of its generation and has no tombstone."""
+        return (view is not None and view.generation == self._generation
+                and not self._tombstones)
+
     # -- batched lookup ----------------------------------------------------------
     def get(self, keys: np.ndarray, stats=None) -> Tuple[np.ndarray, np.ndarray]:
         """Batched aux lookup.
@@ -268,17 +383,19 @@ class AuxTable:
 
         Timed by the ``aux.get`` span, whose args carry the keys probed
         (``keys``), the partitions their key ranges fall in (``visits``,
-        counted alike on both paths), the keys answered on the
-        resident path (``resident``: all of them or none), and the
-        partitioned path's pool misses: those decompressed
-        (``decompressed``) and those of them decompressed on the worker
-        threads (``parallel``).  Those counts are taken once per call:
-        into ``deepmap_aux_keys_total{outcome}`` (found or absent),
-        ``deepmap_aux_path_keys_total{path}`` (resident or partitioned)
-        and ``deepmap_aux_partition_visits_total``, and into ``stats`` (an
+        counted alike on every path), the keys answered from the
+        resident view (``resident``: all of them or none), those of them
+        the device corrected (``device``: 0 here, see
+        :meth:`merge_device`), and the partitioned path's pool misses:
+        those decompressed (``decompressed``) and those of them
+        decompressed on the worker threads (``parallel``).  Those counts
+        are taken once per call: into ``deepmap_aux_keys_total{outcome}``
+        (found or absent), ``deepmap_aux_path_keys_total{path}`` (device,
+        resident or partitioned: each key on one path) and
+        ``deepmap_aux_partition_visits_total``, and into ``stats`` (an
         :class:`~repro.api.plan.ExplainStats`: ``aux_keys``,
-        ``aux_visits``, ``aux_resident_keys``, ``aux_decompressed``,
-        ``aux_parallel``) when one is given.
+        ``aux_visits``, ``aux_resident_keys``, ``aux_device_keys``,
+        ``aux_decompressed``, ``aux_parallel``) when one is given.
         """
         keys = np.asarray(keys, dtype=np.int64)
         n = keys.shape[0]
@@ -288,9 +405,60 @@ class AuxTable:
             return found, out
         with obs.span("aux.get") as span:
             visits, resident, decompressed, parallel = self._probe(keys, found, out)
-        span.args.update(keys=n, visits=visits, resident=resident,
+        self._count(span, stats, n, int(np.count_nonzero(found)), visits, resident,
+                    0, decompressed, parallel)
+        return found, out
+
+    def merge_device(
+        self, keys: np.ndarray, probe: np.ndarray, codes: np.ndarray,
+        hit: np.ndarray, task_idx: List[int], stats=None,
+    ) -> np.ndarray:
+        """The host half of a probe whose search ran on the device over
+        :meth:`device_view` (:meth:`DeviceView.correct`): ``codes`` (rows
+        of ``keys``, the heads ``task_idx``) already hold the view's
+        codes where ``hit``.  The delta overlay then overrides the rows
+        ``probe`` (ascending) of its keys, here.  Counted as :meth:`get`
+        counts the same keys, with every key ``resident`` and
+        ``device``.  Returns the rows of ``probe`` that T_aux answered,
+        ascending."""
+        n = probe.shape[0]
+        if n == 0:
+            return probe
+        with obs.span("aux.get") as span:
+            found = np.zeros(keys.shape[0], dtype=bool)
+            found[probe] = hit[probe]
+            if self._delta:
+                searched = []
+                for i, k in zip(probe.tolist(), keys[probe].tolist()):
+                    row = self._delta.get(k)
+                    if row is None:
+                        searched.append(i)
+                    else:
+                        found[i] = True
+                        codes[i] = row[task_idx]
+                probe = np.asarray(searched, dtype=np.int64)
+            visits = self._visits(keys[probe])
+            rows = np.flatnonzero(found)
+        self._count(span, stats, n, int(rows.size), visits, n, n, 0, 0)
+        return rows
+
+    def _visits(self, keys: np.ndarray) -> int:
+        """The partitions whose key ranges hold ``keys``, as
+        :meth:`_probe` counts them (keys below every partition visit
+        none)."""
+        if (keys[1:] >= keys[:-1]).all():
+            # ordered keys (scan morsels, server batches): one search a boundary
+            at = np.searchsorted(keys, self._boundaries)
+            return int(np.count_nonzero(np.diff(np.append(at, keys.size))))
+        pid = np.searchsorted(self._boundaries, keys, side="right") - 1
+        return int(np.count_nonzero(np.bincount(pid[pid >= 0])))
+
+    def _count(self, span, stats, n, hits, visits, resident, device,
+               decompressed, parallel) -> None:
+        """Record one probe of ``n`` keys: its span's args, the
+        registry's counters and ``stats``."""
+        span.args.update(keys=n, visits=visits, resident=resident, device=device,
                          decompressed=decompressed, parallel=parallel)
-        hits = int(np.count_nonzero(found))
         reg = obs.registry()
         probed = reg.counter(
             "deepmap_aux_keys_total", "Keys probed in T_aux, by outcome."
@@ -299,10 +467,11 @@ class AuxTable:
         probed.inc(n - hits, outcome="absent")
         by_path = reg.counter(
             "deepmap_aux_path_keys_total",
-            "Keys probed in T_aux, by path: the resident sorted view or "
-            "the partitions.",
+            "Keys probed in T_aux, by path: the device copy of the resident "
+            "sorted view, the resident view on the host, or the partitions.",
         )
-        by_path.inc(resident, path="resident")
+        by_path.inc(device, path="device")
+        by_path.inc(resident - device, path="resident")
         by_path.inc(n - resident, path="partitioned")
         reg.counter(
             "deepmap_aux_partition_visits_total",
@@ -313,9 +482,9 @@ class AuxTable:
             stats.aux_keys += n
             stats.aux_visits += visits
             stats.aux_resident_keys += resident
+            stats.aux_device_keys += device
             stats.aux_decompressed += decompressed
             stats.aux_parallel += parallel
-        return found, out
 
     def _probe(
         self, keys: np.ndarray, found: np.ndarray, out: np.ndarray

@@ -337,9 +337,54 @@ class DeepMappingStore(MappingStore):
                 pending.keys[start : start + bs], pending.wanted,
                 want_exists=True,
                 pred_tables=pending.kernel_tables or None,
+                aux=self.aux.device_view(),
             ),
         ))
         pending.next_start = min(start + bs, pending.keys.shape[0])
+
+    def _collect_next(self, pending: _PendingLookup, task_idx, stats: ExplainStats):
+        """Lines 3-8 of Algorithm 1 for the oldest chunk in flight: top
+        the device window up, block on the chunk's result, test
+        existence on the host where the tier did not, and apply the
+        ``T_aux`` override to the existing keys.  Where the engine
+        corrected the codes on the device from a view that is still
+        current, only the delta overlay and the counts run here
+        (``AuxTable.merge_device``); otherwise the host probes
+        (``AuxTable.get``): mesh tickets, tiers beyond int32, tables
+        whose view is not resident or that hold tombstones.  Returns
+        ``(ticket, codes, exists, aux_rows)``: the rows ``T_aux``
+        answered."""
+        _, ticket = pending.tickets.pop(0)
+        t0 = time.perf_counter()
+        # keep the device window full before blocking on this chunk
+        while (
+            len(pending.tickets) < DISPATCH_WINDOW - 1
+            and pending.next_start < pending.keys.shape[0]
+        ):
+            self._dispatch_next_chunk(pending)
+        if not self.aux.device_current(ticket.aux_view):
+            ticket.aux_dev = None  # none given, or written since: the model's codes
+        codes, exists = self.engine.collect(ticket)      # line 3 (inference)
+        stats.infer_s += time.perf_counter() - t0
+        stats.dispatch_s += ticket.dispatch_s
+        stats.wait_s += ticket.wait_s
+        if exists is None:                               # line 5 (existence)
+            with obs.span("store.exist") as span:
+                exists = self.vexist.test(ticket.keys)
+            stats.exist_s += span.seconds
+        t3 = time.perf_counter()
+        # lines 6-8: aux override for existing keys only.  T_aux rows
+        # carry codes for ALL tasks; project to the evaluated ones.
+        exist_idx = np.flatnonzero(exists)
+        if ticket.aux_hit is not None:
+            aux_rows = self.aux.merge_device(
+                ticket.keys, exist_idx, codes, ticket.aux_hit, task_idx, stats)
+        else:
+            found, aux_codes = self.aux.get(ticket.keys[exist_idx], stats)
+            aux_rows = exist_idx[found]
+            codes[aux_rows] = aux_codes[:, task_idx][found]
+        stats.aux_s += time.perf_counter() - t3
+        return ticket, codes, exists, aux_rows
 
     def supports_kernel_filter(
         self, predicates: tuple = (), columns: Optional[Tuple[str, ...]] = None
@@ -456,32 +501,10 @@ class DeepMappingStore(MappingStore):
         exists_parts, match_parts = [], []
         value_parts = {t: [] for t in decode_cols}
         while pending.tickets:
-            start, ticket = pending.tickets.pop(0)
-            # keep the device window full before blocking on this chunk
-            t0 = time.perf_counter()
-            while (
-                len(pending.tickets) < DISPATCH_WINDOW - 1
-                and pending.next_start < keys.shape[0]
-            ):
-                self._dispatch_next_chunk(pending)
-            pred, exists = self.engine.collect(ticket)      # line 3 (inference)
-            stats.infer_s += time.perf_counter() - t0
-            stats.dispatch_s += ticket.dispatch_s
-            stats.wait_s += ticket.wait_s
-            if exists is None:                               # line 5 (existence)
-                with obs.span("store.exist") as span:
-                    exists = self.vexist.test(ticket.keys)
-                stats.exist_s += span.seconds
-            t3 = time.perf_counter()
-            # line 6-8: aux override for existing keys only.  T_aux rows
-            # carry codes for ALL tasks; project to the evaluated ones.
-            exist_idx = np.flatnonzero(exists)
-            found, aux_codes = self.aux.get(ticket.keys[exist_idx], stats)
-            pred[exist_idx[found]] = aux_codes[:, task_idx][found]
-            stats.aux_s += time.perf_counter() - t3
+            ticket, pred, exists, aux_rows = self._collect_next(pending, task_idx, stats)
             if preds:
                 match = self._filter_chunk(
-                    ticket, pred, exists, exist_idx[found], preds, stats
+                    ticket, pred, exists, aux_rows, preds, stats
                 )
                 hit = np.flatnonzero(match)
                 stats.rows_matched += int(hit.size)
@@ -535,33 +558,14 @@ class DeepMappingStore(MappingStore):
         existing rows the corrected codes are exact (the aux table
         overrides every model miss), so any reduction over them equals
         the same reduction over decoded values."""
-        keys, preds = pending.keys, pending.preds
+        preds = pending.preds
+        task_idx = [self.spec.tasks.index(t) for t in pending.wanted]
         while pending.tickets:
-            _, ticket = pending.tickets.pop(0)
-            t0 = time.perf_counter()
-            while (
-                len(pending.tickets) < DISPATCH_WINDOW - 1
-                and pending.next_start < keys.shape[0]
-            ):
-                self._dispatch_next_chunk(pending)
-            codes, exists = self.engine.collect(ticket)
-            stats.infer_s += time.perf_counter() - t0
-            stats.dispatch_s += ticket.dispatch_s
-            stats.wait_s += ticket.wait_s
-            if exists is None:
-                with obs.span("store.exist") as span:
-                    exists = self.vexist.test(ticket.keys)
-                stats.exist_s += span.seconds
-            t3 = time.perf_counter()
-            exist_idx = np.flatnonzero(exists)
-            found, aux_codes = self.aux.get(ticket.keys[exist_idx], stats)
-            task_idx = [self.spec.tasks.index(t) for t in pending.wanted]
-            codes[exist_idx[found]] = aux_codes[:, task_idx][found]
-            stats.aux_s += time.perf_counter() - t3
+            ticket, codes, exists, aux_rows = self._collect_next(pending, task_idx, stats)
             match = None
             if preds:
                 match = self._filter_chunk(
-                    ticket, codes, exists, exist_idx[found], preds, stats
+                    ticket, codes, exists, aux_rows, preds, stats
                 )
                 stats.rows_matched += int(match.sum())
             yield codes, exists, match

@@ -44,6 +44,13 @@ Every path produces byte-identical codes/exists (tested in
 ``tests/test_kernels.py::TestFusedLookupConformance`` and
 ``tests/test_vmem_streaming.py``).
 
+On the ``fused``, ``fused_streamed`` and ``jit_keys`` tiers, ``dispatch(...,
+aux=view)`` also enqueues the ``T_aux`` override of Algorithm 1 (lines
+6-8) on the device, right after the model's call, as a program of its
+own (``DeviceView.correct``, in ``core/aux_table.py``): the codes come
+back already corrected from the device copy of the resident view, with
+a hit mask (``InferTicket.aux_hit``).
+
 The fused tier can additionally evaluate pushdown predicates in-kernel:
 ``dispatch(..., pred_tables=...)`` ships the boolean code tables into
 the ``pallas_call`` and ``InferTicket.match`` carries the match bits
@@ -163,14 +170,16 @@ def _codes_from_keys_jit(
 
 
 class _TaskEntry:
-    """Per-task-subset cache: subset spec + params view, and (lazily)
-    the padded flat device weights the Pallas paths reuse."""
+    """Per-task-subset cache: subset spec + params view, the heads'
+    indices in the whole spec, and (lazily) the padded flat device
+    weights the Pallas paths reuse."""
 
-    __slots__ = ("spec", "params", "_flat", "_wbytes")
+    __slots__ = ("spec", "params", "heads", "_flat", "_wbytes")
 
-    def __init__(self, spec: MLPSpec, params: Dict):
+    def __init__(self, spec: MLPSpec, params: Dict, heads: Tuple[int, ...]):
         self.spec = spec
         self.params = params
+        self.heads = heads
         self._flat: Optional[Tuple[jnp.ndarray, ...]] = None
         self._wbytes = 0
 
@@ -195,6 +204,7 @@ class InferTicket:
     path: str
     keys: np.ndarray                       # original int64 chunk keys
     want_exists: bool = False
+    keys_dev: object = None                # (n_pad,) int32 keys, -1 padded (int32 tiers)
     codes_dev: object = None               # device array / tuple, path-shaped
     exists_dev: object = None              # (n_pad,) int32 device array (fused)
     match_dev: object = None               # (n_pad,) int32 kernel match bits
@@ -205,6 +215,13 @@ class InferTicket:
     #: the host filter instead).  Aux-overridden rows still need the
     #: host patch: the kernel matched on the *model* code.
     match: Optional[np.ndarray] = None
+    #: The device ``T_aux`` override (``DeviceView.correct``'s outputs)
+    #: and the view it read; None where the tier or the caller gave none.
+    aux_dev: object = None
+    aux_view: object = None
+    #: Host copy of its hit mask, filled by ``collect`` where it
+    #: returned the corrected codes.
+    aux_hit: Optional[np.ndarray] = None
     #: Seconds of the ``engine.dispatch`` and ``engine.wait`` spans.
     dispatch_s: float = 0.0
     wait_s: float = 0.0
@@ -296,7 +313,8 @@ class InferenceEngine:
                             "shared": self.params["shared"],
                             "heads": {t: self.params["heads"][t] for t in tasks},
                         }
-                    entry = _TaskEntry(spec, params)
+                    entry = _TaskEntry(spec, params, tuple(
+                        self.spec.tasks.index(t) for t in spec.tasks))
                     self._entries[tasks] = entry
                     self.stats.bump("weight_cache_misses")
         return entry
@@ -381,11 +399,17 @@ class InferenceEngine:
         tasks: Optional[Tuple[str, ...]] = None,
         want_exists: bool = False,
         pred_tables: Optional[Tuple[Tuple[str, np.ndarray], ...]] = None,
+        aux=None,
     ) -> InferTicket:
         """Enqueue device inference for one key chunk; returns
         immediately (JAX async dispatch).  ``want_exists`` additionally
         requests existence bits — in-kernel on the fused path, host
         ``BitVector.test`` at collect time otherwise.
+
+        ``aux`` — a :class:`~repro.core.aux_table.DeviceView` — asks for
+        the ``T_aux`` override on the device (``aux.correct``) after the
+        model's call, on the tiers that upload int32 keys; the others
+        leave ``aux_dev`` None and the caller probes on the host.
 
         ``pred_tables`` — ``((column, bool_code_table), ...)`` — asks
         the fused kernel to evaluate the pushdown predicate conjunction
@@ -431,6 +455,14 @@ class InferenceEngine:
                 ticket = self._dispatch_jit_digits(keys, tasks, entry, bucket,
                                                    want_exists)
             ticket.task_order = entry.spec.tasks
+            if aux is not None and ticket.keys_dev is not None:
+                codes = ticket.codes_dev
+                self.stats.note_compile(
+                    ("aux_correct", bucket, len(entry.heads), aux.signature,
+                     len(codes) if isinstance(codes, tuple) else 0)
+                )
+                ticket.aux_dev = aux.correct(codes, ticket.keys_dev, entry.heads)
+                ticket.aux_view = aux
         ticket.dispatch_s = span.seconds
         return ticket
 
@@ -478,14 +510,15 @@ class InferenceEngine:
             ("fused", entry.spec, self._pos_ops, bucket,
              words.shape[0], ptasks, tuple(t.shape[0] for t in ptabs))
         )
+        keys_dev = jnp.asarray(self._keys_i32(keys, bucket))
         codes, exists, match = kops.fused_lookup(
-            flat, entry.spec, jnp.asarray(self._keys_i32(keys, bucket)),
+            flat, entry.spec, keys_dev,
             self._pos_ops, words, self.encoder.capacity,
             tile_n=self.tile_n, interpret=self.interpret,
             pred_tables=ptabs, pred_tasks=ptasks,
         )
         return InferTicket(n=keys.shape[0], tasks=tasks, path="fused",
-                           keys=keys, want_exists=True,
+                           keys=keys, want_exists=True, keys_dev=keys_dev,
                            codes_dev=codes, exists_dev=exists,
                            match_dev=match)
 
@@ -525,7 +558,7 @@ class InferenceEngine:
                 exists_dev = ex
         return InferTicket(n=keys.shape[0], tasks=tasks,
                            path="fused_streamed", keys=keys,
-                           want_exists=with_exists,
+                           want_exists=with_exists, keys_dev=keys_dev,
                            codes_dev=tuple(codes_pages),
                            exists_dev=exists_dev)
 
@@ -534,12 +567,14 @@ class InferenceEngine:
         self.stats.note_compile(
             ("jit_keys", entry.spec, self._pos_ops, bucket)
         )
+        keys_dev = jnp.asarray(self._keys_i32(keys, bucket))
         codes = _codes_from_keys_jit(
-            entry.params, jnp.asarray(self._keys_i32(keys, bucket)),
+            entry.params, keys_dev,
             entry.spec, self._pos_ops, self.encoder.capacity,
         )
         return InferTicket(n=keys.shape[0], tasks=tasks, path="jit_keys",
-                           keys=keys, want_exists=want_exists, codes_dev=codes)
+                           keys=keys, want_exists=want_exists, keys_dev=keys_dev,
+                           codes_dev=codes)
 
     def _host_digits(self, keys: np.ndarray, bucket: int):
         """Legacy host featurization for >int32 domains: digits of
@@ -585,23 +620,31 @@ class InferenceEngine:
         ``exists`` is a bool array ONLY when the fused kernel computed
         it on-device; on every other path it is None and the caller
         runs (and times) the host ``BitVector.test`` itself — keeping
-        the existence stage visible in per-stage stats."""
+        the existence stage visible in per-stage stats.
+
+        Where the ticket carries the device ``T_aux`` override
+        (``aux_dev``, which a caller whose view went stale clears), the
+        codes are the corrected ones and ``ticket.aux_hit`` is filled;
+        otherwise they are the model's."""
         n = ticket.n
         if ticket.path == "empty":
             return np.zeros((n, len(ticket.tasks)), dtype=np.int32), None
 
         with obs.span("engine.wait") as span:
-            if ticket.path == "pallas_digits":
-                codes = np.concatenate(
-                    [np.asarray(o)[:n] for o in ticket.codes_dev], axis=1
-                )
-            elif ticket.path == "fused_streamed":
-                # one (n_pad, page_tasks) block per page, spec order overall
-                codes = np.concatenate(
-                    [np.asarray(c)[:n] for c in ticket.codes_dev], axis=1
-                )
+            codes_dev, aux = ticket.codes_dev, ticket.aux_dev
+            if aux is not None:
+                codes_dev = aux[0]
+            elif ticket.path in ("pallas_digits", "fused_streamed"):
+                # one (n_pad, k) block per kernel output or page, spec order
+                codes_dev = tuple(codes_dev)
+            # every output the host reads, copied out together
+            host = jax.device_get((codes_dev, ticket.exists_dev, ticket.match_dev,
+                                   aux[1] if aux is not None else None))
+            codes_h, exists_h, match_h, aux_h = host
+            if isinstance(codes_h, tuple):
+                codes = np.concatenate([c[:n] for c in codes_h], axis=1)
             else:
-                codes = np.asarray(ticket.codes_dev)[:n]
+                codes = codes_h[:n]
             if ticket.task_order and ticket.tasks != ticket.task_order:
                 # requested projection order differs from spec canonical
                 perm = [ticket.task_order.index(t) for t in ticket.tasks]
@@ -612,10 +655,12 @@ class InferenceEngine:
                 codes[~ticket.in_cap] = 0
 
             exists = None
-            if ticket.exists_dev is not None:
-                exists = np.asarray(ticket.exists_dev)[:n].astype(bool)
-            if ticket.match_dev is not None:
-                ticket.match = np.asarray(ticket.match_dev)[:n].astype(bool)
+            if exists_h is not None:
+                exists = exists_h[:n].astype(bool)
+            if match_h is not None:
+                ticket.match = match_h[:n].astype(bool)
+            if aux_h is not None:
+                ticket.aux_hit = aux_h[:n]
         ticket.wait_s = span.seconds
         return codes, exists
 

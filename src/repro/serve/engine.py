@@ -39,8 +39,8 @@ class ServeStats:
     ``serve.merge`` span), route (key-source/plan compile),
     infer/exist/aux/decode from the store hooks — ``dispatch_s`` and
     ``wait_s`` are the engine's spans inside ``infer_s``, ``aux_keys``,
-    ``aux_visits``, ``aux_resident_keys``, ``aux_decompressed`` and
-    ``aux_parallel`` the ``T_aux`` probe counts — filter (zero unless a
+    ``aux_visits``, ``aux_resident_keys``, ``aux_device_keys``,
+    ``aux_decompressed`` and ``aux_parallel`` the ``T_aux`` probe counts — filter (zero unless a
     predicate plan is served), gather (the ``serve.scatter`` span back
     to requesters).  Requests, keys and latencies are also mirrored
     into the process metrics registry under ``deepmap_serve_*`` for
@@ -63,6 +63,7 @@ class ServeStats:
     aux_keys: int = 0
     aux_visits: int = 0
     aux_resident_keys: int = 0
+    aux_device_keys: int = 0
     aux_decompressed: int = 0
     aux_parallel: int = 0
     cache_hits: int = 0
@@ -167,6 +168,7 @@ class LookupServer:
             self.stats.aux_keys += morsel.stats.aux_keys
             self.stats.aux_visits += morsel.stats.aux_visits
             self.stats.aux_resident_keys += morsel.stats.aux_resident_keys
+            self.stats.aux_device_keys += morsel.stats.aux_device_keys
             self.stats.aux_decompressed += morsel.stats.aux_decompressed
             self.stats.aux_parallel += morsel.stats.aux_parallel
             self.stats.filter_s += morsel.stats.filter_s

@@ -242,7 +242,7 @@ class TestProbePaths:
         (span,) = trc.spans("aux.get")
         # over the pool, each 80-byte partition is a wave of its own (half
         # the 200-byte budget holds one): three misses, each inline
-        assert span.args == {"keys": 6, "visits": 3, "resident": resident,
+        assert span.args == {"keys": 6, "visits": 3, "resident": resident, "device": 0,
                              "decompressed": 0 if resident else 3, "parallel": 0}
         by_path = reg.counter("deepmap_aux_path_keys_total")
         assert by_path.value(path="resident") == resident

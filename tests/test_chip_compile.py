@@ -242,3 +242,31 @@ def test_mesh_scatter_four_devices(topo):
         *(stack(a.shape, a.dtype) for a in flat),
     ).compile().as_text()
     assert "all-gather" in text
+
+
+@pytest.mark.parametrize("keys,rows,heads,pages", [
+    (1_920_800, 1_870_000, 8, 1),    # customer_demographics' T_aux, every head (fused)
+    (1_920_800, 1_870_000, 1, 1),    # a group-by's one head
+    (48_000_008, 6_001_215, 10, 1),  # lineitem's T_aux, every head (jit_keys)
+    (48_000_008, 6_001_215, 10, 3),  # the same from three streamed pages
+])
+def test_aux_correction_at_the_benchmark_sizes(one_chip, keys, rows, heads, pages):
+    """The device ``T_aux`` override over a device copy of the resident
+    view at the benchmark relations' sizes: a module of its own, that
+    gathers only the wanted heads' codes (its scratch holds no copy of
+    the view)."""
+    from repro.core.aux_table import _aux_correct
+
+    def arg(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    batch = 1 << 16
+    cuts = [heads * i // pages for i in range(pages + 1)]
+    codes = tuple(arg((batch, b - a)) for a, b in zip(cuts, cuts[1:]))
+    nbits = keys // 32 + 2
+    compiled = _aux_correct.lower(
+        codes if pages > 1 else codes[0], arg((batch,)), arg((nbits,), jnp.uint32),
+        arg((nbits,)), tuple(arg((rows,)) for _ in range(heads)),
+    ).compile()
+    assert compiled.as_text().startswith("HloModule jit__aux_correct,")
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * rows

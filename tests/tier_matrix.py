@@ -239,8 +239,8 @@ def served_tiers(setting: str, store):
     dispatch = eng.dispatch
     tiers: List[Tuple[str, str]] = []
 
-    def spy(keys, tasks=None, want_exists=False, pred_tables=None):
-        ticket = dispatch(keys, tasks, want_exists, pred_tables)
+    def spy(keys, tasks=None, want_exists=False, pred_tables=None, aux=None):
+        ticket = dispatch(keys, tasks, want_exists, pred_tables, aux)
         if ticket.path != "empty":
             tiers.append((ticket.path,
                           expected_tier(setting, eng, ticket.task_order, want_exists)))
